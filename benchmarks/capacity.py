@@ -28,7 +28,8 @@ import json
 
 import numpy as np
 
-from common import bench_cfg, emit_bench, oracle
+from common import (add_chip_flag, bench_cfg, bench_setup, emit_bench,
+                    oracle)
 from repro.core import PFOIndex
 
 
@@ -113,7 +114,9 @@ def main():
     ap.add_argument("--json", default=None)
     ap.add_argument("--out-dir", default=".",
                     help="directory for BENCH_capacity.json telemetry")
+    add_chip_flag(ap)
     args = ap.parse_args()
+    bench_setup(args.chip)
 
     kw: dict = dict(dim=args.dim, bloom_bits=0, bloom_hashes=0,
                     snap_probes=2)

@@ -1,6 +1,7 @@
 """Shared benchmark utilities: datasets, metrics, timing."""
 from __future__ import annotations
 
+import sys
 import time
 
 import jax
@@ -87,6 +88,29 @@ def bench_env() -> dict:
         "n_devices": jax.device_count(),
         "python": platform.python_version(),
     }
+
+
+def add_chip_flag(ap) -> None:
+    ap.add_argument("--chip", action="store_true",
+                    help="measure on the chip: fail unless JAX finds a TPU")
+
+
+def bench_setup(chip: bool) -> dict:
+    """First call of a benchmark's main, before anything compiles:
+    places JAX's compile cache and names the device the run is on.  A
+    run asked onto the chip (``--chip``) that finds none fails; a CPU
+    run says so, since its times are not device metrics."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    env = bench_env()
+    if chip and env["backend"] != "tpu":
+        raise SystemExit(f"--chip: JAX found no TPU (platform "
+                         f"{env['backend']!r})")
+    print(f"[bench] device: {env['backend']} {env['device_kind']} "
+          f"x{env['n_devices']}" + ("" if env["backend"] == "tpu" else
+                                    " -- not a chip run; times are not "
+                                    "device metrics"), file=sys.stderr)
+    return env
 
 
 def emit_bench(name: str, config: dict, results: dict, obs=None,

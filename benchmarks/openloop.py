@@ -39,7 +39,7 @@ import time
 
 import numpy as np
 
-from common import bench_cfg, emit_bench
+from common import add_chip_flag, bench_cfg, bench_setup, emit_bench
 from repro.core import PFOIndex
 from repro.obs import Obs
 from repro.serving import StreamConfig, StreamEngine
@@ -162,7 +162,9 @@ def main():
     ap.add_argument("--json", default=None)
     ap.add_argument("--out-dir", default=".",
                     help="where BENCH_openloop.json lands")
+    add_chip_flag(ap)
     args = ap.parse_args()
+    bench_setup(args.chip)
     if args.smoke:
         args.requests, args.seed_vecs = 400, 500
         args.max_batch = 64

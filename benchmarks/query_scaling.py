@@ -25,7 +25,8 @@ import json
 import jax
 import numpy as np
 
-from common import bench_cfg, clustered_dataset, timeit
+from common import (add_chip_flag, bench_cfg, bench_setup,
+                    clustered_dataset, timeit)
 from repro.core import PFOIndex
 from repro.core.index import query_step
 
@@ -56,7 +57,9 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes + the Q=64 <= 1.5x Q=1 gate (CI)")
     ap.add_argument("--json", default=None)
+    add_chip_flag(ap)
     args = ap.parse_args()
+    bench_setup(args.chip)
     qs = [int(x) for x in args.qs.split(",")]
     if args.smoke:
         args.n, qs = 1000, [1, 16, 64]

@@ -10,17 +10,21 @@ import os
 import sys
 import time
 
-# Interpret-mode Pallas is a correctness tool (Python-executed kernel
-# bodies); benchmarking it would measure the interpreter.  The jnp ref
-# path is the same math the TPU kernels fuse.
-os.environ.setdefault("REPRO_PALLAS", "off")
-
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list, e.g. fig5,fig10")
+    from .common import add_chip_flag, bench_setup
+    add_chip_flag(ap)
     args = ap.parse_args()
+    if not args.chip:
+        # Interpret-mode Pallas is a correctness tool (Python-executed
+        # kernel bodies); benchmarking it would measure the
+        # interpreter.  The jnp ref path is the same math the TPU
+        # kernels fuse.  A --chip run times the compiled kernels.
+        os.environ.setdefault("REPRO_PALLAS", "off")
+    bench_setup(args.chip)
 
     from . import paper_figs
 

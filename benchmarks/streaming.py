@@ -23,7 +23,8 @@ its sustained throughput against the single-chip engine.  On CPU the
 mesh uses host-platform virtual devices; if the platform exposes too
 few, the benchmark re-execs itself with
 ``--xla_force_host_platform_device_count`` set (the flag must precede
-jax initialization).
+jax initialization).  On an accelerator it never re-execs: a child
+could not take the chips the parent holds, so too few devices fail.
 
     PYTHONPATH=src python benchmarks/streaming.py [--smoke] [--distributed]
 """
@@ -38,7 +39,8 @@ import time
 
 import numpy as np
 
-from common import bench_cfg, clustered_dataset, emit_bench
+from common import (add_chip_flag, bench_cfg, bench_setup,
+                    clustered_dataset, emit_bench)
 from repro.core import PFOIndex
 from repro.core.index import delete_step, insert_step, query_step
 from repro.obs import Obs
@@ -153,26 +155,25 @@ def main():
     ap.add_argument("--json", default=None)
     ap.add_argument("--out-dir", default=".",
                     help="where BENCH_streaming.json + trace.json land")
+    add_chip_flag(ap)
     args = ap.parse_args()
+    env = bench_setup(args.chip)
     if args.distributed:
-        import jax
         need = args.n_model * args.n_data
-        if jax.device_count() < need:
-            # the device-count flag must be set before jax initializes:
-            # re-exec ONCE with it in the environment.  The sentinel
-            # stops an exec loop on platforms where forcing host
-            # devices cannot raise device_count (e.g. a GPU backend).
-            if os.environ.get("_STREAMING_BENCH_REEXEC"):
+        if env["n_devices"] < need:
+            if env["backend"] != "cpu":
+                # one process per chip: a re-exec'd child could not
+                # take the chips this process already holds
                 raise SystemExit(
-                    f"--distributed needs {need} devices but the "
-                    f"platform exposes {jax.device_count()} even with "
-                    "host-platform devices forced; run on CPU or a "
-                    "larger accelerator mesh")
-            env = dict(os.environ, _STREAMING_BENCH_REEXEC="1")
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                                + " --xla_force_host_platform_device_count"
-                                  f"={need}")
-            sys.exit(subprocess.call([sys.executable] + sys.argv, env=env))
+                    f"--distributed needs {need} devices; the "
+                    f"{env['backend']} platform has {env['n_devices']}")
+            # host-platform devices must be forced before jax
+            # initializes: re-exec once with the flag set
+            child = dict(os.environ)
+            child["XLA_FLAGS"] = (child.get("XLA_FLAGS", "")
+                                  + " --xla_force_host_platform_device_count"
+                                    f"={need}")
+            sys.exit(subprocess.call([sys.executable] + sys.argv, env=child))
     if args.smoke:
         args.requests, args.seed_vecs = 600, 500
         args.max_batch, args.flush_every = 64, 64

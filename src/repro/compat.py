@@ -1,124 +1,38 @@
-"""JAX API compatibility shims.
-
-The repo targets a range of JAX versions; two API families drifted:
-
-``shard_map``
-    New JAX exposes ``jax.shard_map(f, mesh=..., in_specs=...,
-    out_specs=..., check_vma=...)``; older releases only have
-    ``jax.experimental.shard_map.shard_map`` whose replication-check
-    kwarg is named ``check_rep``.  :func:`shard_map` resolves the
-    implementation once and translates the kwarg.
-
-``set_mesh`` / ambient mesh
-    New JAX carries an ambient (abstract) mesh set with
-    ``jax.sharding.set_mesh`` / ``use_mesh`` and read with
-    ``jax.sharding.get_abstract_mesh``.  Older releases have none of
-    these, so :func:`set_mesh` falls back to a module-level context
-    variable and :func:`get_mesh` reads whichever source exists.
+"""Mesh and pytree helpers, spelled once on JAX 0.9's native APIs.
 
 Everything mesh-aware in this repo (``core.distributed``,
-``models.moe``, ``launch.dryrun``, the shard_map tests) routes through
-this module instead of touching ``jax.shard_map`` / ``jax.sharding``
-directly.
+``models.moe``, ``launch.dryrun``, ``sharding.policy``, the shard_map
+tests) routes through these names rather than touching
+``jax.shard_map`` / ``jax.sharding`` directly.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any
-
 import jax
+from jax.sharding import AxisType
 
-_AMBIENT_MESH: list[Any] = []          # stack; top is the current mesh
+#: context manager installing ``mesh`` as the ambient mesh
+set_mesh = jax.sharding.set_mesh
 
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "check_vma"
-    from jax.experimental.shard_map import shard_map as fn  # noqa: F811
-    return fn, "check_rep"
+#: ``jax.tree.flatten_with_path``
+tree_flatten_with_path = jax.tree.flatten_with_path
 
 
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma: bool = True):
-    """Version-agnostic ``shard_map``.
-
-    ``mesh=None`` uses the ambient mesh from :func:`get_mesh` (matching
-    new-JAX behaviour); the replication/VMA check kwarg is translated to
-    whatever the resolved implementation expects.
-    """
-    if mesh is None:
-        mesh = get_mesh()
-        if mesh is None:
-            raise ValueError(
-                "compat.shard_map: no mesh given and no ambient mesh set "
-                "(use compat.set_mesh(...) or pass mesh=...)")
-    kwargs = {_CHECK_KW: check_vma}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Uses ``jax.sharding.set_mesh``/``use_mesh`` when available so jitted
-    code sees the real ambient mesh; otherwise maintains a module-level
-    stack that :func:`get_mesh` consults.
-    """
-    native = getattr(jax.sharding, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None)
-    _AMBIENT_MESH.append(mesh)
-    try:
-        if native is not None:
-            with native(mesh):
-                yield mesh
-        else:
-            yield mesh
-    finally:
-        _AMBIENT_MESH.pop()
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
+    """``jax.shard_map`` over an explicit mesh."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def get_mesh():
-    """Current ambient mesh, or ``None``.
-
-    Prefers the native abstract mesh (new JAX), then the compat stack.
-    An "empty" native mesh (no axes) counts as unset.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-        if getattr(mesh, "axis_names", ()):
-            return mesh
-    return _AMBIENT_MESH[-1] if _AMBIENT_MESH else None
+    """The ambient (abstract) mesh, or ``None`` when none is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
-def make_mesh(shape: tuple, axis_names: tuple, devices=None):
-    """Version-agnostic mesh construction.
-
-    New JAX exposes ``jax.make_mesh(shape, axis_names)``; older
-    releases build meshes from ``mesh_utils.create_device_mesh``.  An
-    explicit ``devices`` list (e.g. a prefix of the host-platform
-    virtual devices) bypasses both and reshapes directly.
-    """
-    from jax.sharding import Mesh
-    import numpy as np
-
-    if devices is not None:
-        return Mesh(np.asarray(devices).reshape(shape), axis_names)
-    fn = getattr(jax, "make_mesh", None)
-    if fn is not None:
-        return fn(shape, axis_names)
-    from jax.experimental import mesh_utils
-    return Mesh(mesh_utils.create_device_mesh(shape), axis_names)
-
-
-def tree_flatten_with_path(tree):
-    """``jax.tree.flatten_with_path`` (new) / ``jax.tree_util`` (old)."""
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree)
+def make_mesh(shape: tuple, axis_names: tuple, devices) -> jax.sharding.Mesh:
+    """Mesh over an explicit device list (e.g. a prefix of the
+    host-platform virtual devices), with ``Auto`` axes: sharding stays
+    in the compiler's hands, as every caller here expects."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)

@@ -211,9 +211,11 @@ def _batch_spec(dcfg: DistConfig) -> P:
     return P(axes if len(axes) > 1 else axes[0])
 
 
-def _dedup_topk(pid: jax.Array, pd: jax.Array, k: int):
-    """Top-k by distance with id dedupe (flat (N,) id/dist arrays)."""
-    neg, idx = jax.lax.top_k(-pd, min(2 * k, pd.shape[0]))
+def _dedup_topk(pid: jax.Array, pd: jax.Array, k: int, copies: int):
+    """Top-k by distance with id dedupe (flat (N,) id/dist arrays), where
+    an id occurs at most ``copies`` times (once per shard that found
+    it): the ``copies * k`` nearest entries hold k distinct ids."""
+    neg, idx = jax.lax.top_k(-pd, min(copies * k, pd.shape[0]))
     ii = pid[idx]
     same = ii[:, None] == ii[None, :]
     dup = jnp.tril(same, -1).any(axis=1) & (ii >= 0)
@@ -535,7 +537,7 @@ def make_dist_query(dcfg: DistConfig, mesh: Mesh, k: int,
         (pd_g,) = gather_mailbox(rbox, pd)
         pd_r = jnp.where(rbox >= 0, pd_g, jnp.inf)
         out_ids, out_d = jax.vmap(
-            lambda ii, dd: _dedup_topk(ii, dd, k))(pid_r, pd_r)
+            lambda ii, dd: _dedup_topk(ii, dd, k, S))(pid_r, pd_r)
         out = (out_ids, out_d)
         if with_drop_count:
             out = out + (dropped,)

@@ -58,9 +58,14 @@ Two traversal modes exist for the read path (``TreeConfig.traversal``):
     many ids colliding on the full consumed prefix: adversarial-only,
     and the bounded-bucket spread discipline (§5.1) assumes it away.
 
-The write path (insert / delete / spread) keeps its while_loops: writes
-are applied sequentially within a tree by construction (the actor
-mailbox scan), so there is no lockstep batch to penalize.
+The write path applies each tree's mailbox sequentially (the actor
+mailbox scan).  The single-tree ``tree_insert`` / ``tree_delete`` keep
+their while_loops; the forest insert that the index runs
+(``forest_insert_dispatched``) advances every tree one request per
+scan step with the same fixed-trip, flat-indexed discipline as the
+read path, because a vmap of ``tree_insert`` turns each of its conds
+and loops into a select over whole arenas — every step rewrote the
+forest (~56 ms per step, measured on a TPU v5e with 2,560 trees).
 """
 from __future__ import annotations
 
@@ -296,17 +301,20 @@ def tree_lookup_masked(st: TreeState, h: jax.Array, vid: jax.Array,
 # ----------------------------------------------------------------------
 def _forest_descend_masked(forest: TreeState, tids: jax.Array,
                            hs: jax.Array, cfg: TreeConfig):
-    """Batched fixed-trip descent: tids/hs (N,) -> (node, sl, v) (N,)."""
+    """Batched fixed-trip descent: tids/hs (N,) -> (node, depth, sl, v)
+    (N,) — :func:`_descend_masked` row for row."""
     sl = key_bits(hs, cfg.skip_bits, cfg.log2_l)
     node = jnp.zeros_like(tids)
+    depth = jnp.zeros_like(tids)
     v = forest.slots[tids, node, sl]
     for d in range(1, cfg.max_depth):
         go = v < 0
         node = jnp.where(go, -v - 1, node)
         sl = jnp.where(go, key_bits(hs, cfg.skip_bits + d * cfg.log2_l,
                                     cfg.log2_l), sl)
+        depth = depth + go.astype(jnp.int32)
         v = jnp.where(go, forest.slots[tids, node, sl], v)
-    return node, sl, v
+    return node, depth, sl, v
 
 
 def _forest_chain_slots(forest: TreeState, tids: jax.Array,
@@ -331,7 +339,7 @@ def forest_query_masked(forest: TreeState, tids: jax.Array, hs: jax.Array,
     """Batched fixed-trip probes: (N,) -> ids/vals (N, max_candidates), n
     (N,).  Row-for-row identical to vmapping the single-tree query."""
     n = tids.shape[0]
-    node, sl, v = _forest_descend_masked(forest, tids, hs, cfg)
+    node, _, sl, v = _forest_descend_masked(forest, tids, hs, cfg)
     mc = cfg.max_chain_eff
     if cfg.sibling_probe:
         sls = sl[:, None] ^ jnp.arange(cfg.l, dtype=jnp.int32)[None, :]
@@ -365,7 +373,7 @@ def forest_query_masked(forest: TreeState, tids: jax.Array, hs: jax.Array,
 def forest_lookup_masked(forest: TreeState, tids: jax.Array, hs: jax.Array,
                          vids: jax.Array, cfg: TreeConfig):
     """Batched fixed-trip exact-id lookup: (N,) -> (val, found) (N,)."""
-    _, _, v = _forest_descend_masked(forest, tids, hs, cfg)
+    _, _, _, v = _forest_descend_masked(forest, tids, hs, cfg)
     heads = jnp.where(v > 0, v, 0)
     flat = _forest_chain_slots(forest, tids, heads, cfg.max_chain_eff)
     valid = flat >= 0
@@ -629,20 +637,86 @@ def forest_insert_dispatched(forest: TreeState, per_tree_h: jax.Array,
     """Apply pre-dispatched requests: (T, K) arrays, -1 id == padding.
 
     Each tree consumes its K-slot segment sequentially (the actor's
-    single-writer mailbox, as a scan); trees run in parallel (vmap).
+    single-writer mailbox, as a scan over K); every step advances all
+    trees by one request.  A step is :func:`tree_insert` row for row,
+    written as flat batched gathers/scatters into the stacked arenas
+    (the forest-level masked traversal's idiom): it touches only the
+    (T,) elements it reads and writes, where a vmap of the per-tree
+    insert turns each cond and while_loop into selects over whole
+    arenas — every step would then rewrite the forest.
     """
-    def per_tree(st, hs, vids, vals):
-        def step(st, x):
-            h, vid, val = x
-            st = jax.lax.cond(
-                vid >= 0,
-                lambda s: tree_insert(s, h, vid, val, cfg),
-                lambda s: s, st)
-            return st, ()
-        st, _ = jax.lax.scan(step, st, (hs, vids, vals))
-        return st
+    T = forest.slots.shape[0]
+    tid = jnp.arange(T, dtype=jnp.int32)
+    ml, mn = cfg.max_leaves, cfg.max_nodes
 
-    return jax.vmap(per_tree)(forest, per_tree_h, per_tree_id, per_tree_val)
+    def step(f: TreeState, x):
+        h, vid, val = x
+        h = h.astype(jnp.uint32)
+        valid = vid >= 0
+        node, depth, sl, v = _forest_descend_masked(f, tid, h, cfg)
+
+        # leaf allocation (_alloc_leaf): pop the free list, else bump
+        use_free = f.free_head > 0
+        free_idx = f.free_head - 1
+        ok = use_free | (f.leaf_cnt < ml)
+        act = valid & ok
+        leaf = jnp.where(use_free, free_idx, f.leaf_cnt)
+        popped = f.leaf_next[tid, jnp.maximum(free_idx, 0)]
+        w = jnp.where(act, leaf, ml)             # masked rows -> dropped
+
+        # prepend the record to the landing chain
+        f = f._replace(
+            leaf_key=f.leaf_key.at[tid, w].set(h, mode="drop"),
+            leaf_id=f.leaf_id.at[tid, w].set(vid, mode="drop"),
+            leaf_val=f.leaf_val.at[tid, w].set(val, mode="drop"),
+            leaf_next=f.leaf_next.at[tid, w].set(v, mode="drop"),
+            free_head=jnp.where(act & use_free, popped, f.free_head),
+            leaf_cnt=f.leaf_cnt + (act & ~use_free).astype(jnp.int32),
+            n_items=f.n_items + act.astype(jnp.int32),
+            overflow=f.overflow + (valid & ~ok).astype(jnp.int32))
+
+        # spread test: chain length from the new head, counted to t + 1
+        clen = jnp.ones_like(tid)
+        cur = v
+        for _ in range(cfg.t):
+            alive = cur > 0
+            clen = clen + alive.astype(jnp.int32)
+            cur = jnp.where(alive, f.leaf_next[tid, jnp.maximum(cur - 1, 0)],
+                            0)
+        nn = f.node_cnt
+        split = (act & (clen > cfg.t) & (depth + 1 < cfg.max_depth)
+                 & (nn < mn))
+        head = jnp.where(split, -(nn + 1), leaf + 1)
+        f = f._replace(
+            slots=f.slots.at[tid, jnp.where(act, node, mn), sl].set(
+                head, mode="drop"),
+            node_cnt=nn + split.astype(jnp.int32))
+
+        # spread the chain into the new node one level down.  A chain
+        # being spread at depth d holds at most t + 1 + d leaves (a
+        # spread hands a child slot at most the chain it moved, and the
+        # next insert there spreads again), so t + max_depth trips walk
+        # it whole.
+        cur = jnp.where(split, leaf + 1, 0)
+        start = cfg.skip_bits + (depth + 1) * cfg.log2_l
+        nn_r = jnp.minimum(nn, mn - 1)
+        for _ in range(cfg.t + cfg.max_depth):
+            alive = cur > 0
+            lf = jnp.maximum(cur - 1, 0)
+            nxt = f.leaf_next[tid, lf]
+            csl = key_bits(f.leaf_key[tid, lf], start, cfg.log2_l)
+            prev = f.slots[tid, nn_r, csl]
+            f = f._replace(
+                leaf_next=f.leaf_next.at[tid, jnp.where(alive, lf, ml)].set(
+                    prev, mode="drop"),
+                slots=f.slots.at[tid, jnp.where(alive, nn, mn), csl].set(
+                    cur, mode="drop"))
+            cur = jnp.where(alive, nxt, 0)
+        return f, ()
+
+    xs = (per_tree_h.T, per_tree_id.T, per_tree_val.T)
+    forest, _ = jax.lax.scan(step, forest, xs)
+    return forest
 
 
 def forest_query(forest: TreeState, tree_ids: jax.Array, hs: jax.Array,

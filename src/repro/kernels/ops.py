@@ -1,11 +1,15 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Responsibilities: shape padding to kernel alignment, interpret-mode
-selection (CPU validates the kernel bodies in Python; TPU compiles
-them), and small epilogues (distance finalize, masking) that don't
-belong in the kernels.  ``REPRO_PALLAS=off`` falls back to the ref.py
-oracles end-to-end, which is also the path the 512-device dry-run uses
-(Pallas does not lower on the host platform).
+selection (on the CPU backend the kernel bodies run in Pallas
+interpret mode; on any other backend they are always compiled), and
+small epilogues (distance finalize, masking) that don't belong in the
+kernels.  The ranking hot path is :func:`gather_rank`: a DMA row
+gather that leaves the store (and the cold tier's staging arena) in
+HBM and fetches each candidate row by slot id into VMEM
+(``kernels/gather_rank.py``).  ``REPRO_PALLAS=off`` falls back to the
+ref.py oracles end-to-end, which is also the path the 512-device
+dry-run uses (Pallas does not lower on the host platform).
 """
 from __future__ import annotations
 
@@ -18,7 +22,7 @@ import jax.numpy as jnp
 from . import ref
 from .gather_rank import gather_rank_pallas, gather_rank_staged_pallas
 from .hamming import hamming_pallas
-from .lsh_hash import lsh_hash_pallas
+from .lsh_hash import lsh_hash_pallas, pack_weights
 from .pair_dist import pair_dist_pallas
 from .rank_candidates import rank_dots_pallas
 
@@ -28,8 +32,8 @@ def _use_pallas() -> bool:
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] == "1"
+    """Interpret mode exactly on the CPU backend: a chip always runs
+    the compiled kernels."""
     return jax.default_backend() == "cpu"
 
 
@@ -51,12 +55,19 @@ def lsh_hash(x: jax.Array, table_proj: jax.Array, M: int = 32) -> jax.Array:
     assert p % M == 0 and M == 32
     if not _use_pallas():
         return ref.ref_lsh_hash(x, table_proj)
-    bn, bp, bk = 128, 128, 256
+    bn, bk = 128, 128
     xp = _pad_to(_pad_to(x, 0, bn), 1, bk)
-    ap = _pad_to(_pad_to(table_proj, 0, bk), 1, bp)
-    out = lsh_hash_pallas(xp, ap, bn=bn, bp=bp, bk=bk,
+    ap = _pad_to(_pad_to(table_proj, 0, bk), 1, 128)
+    hi, lo = _pack_weights(ap.shape[1])
+    out = lsh_hash_pallas(xp, ap, hi, lo, bn=bn, bk=bk,
                           interpret=_interpret())
     return out[:n, :p // 32]
+
+
+@functools.lru_cache(maxsize=None)
+def _pack_weights(p: int):
+    words = p // 32
+    return pack_weights(p, words + (-words) % 128)
 
 
 def rank_dots(q: jax.Array, x: jax.Array) -> jax.Array:

@@ -109,11 +109,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._annotate = None
         if jax_annotations:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotate = TraceAnnotation
-            except Exception:                    # pragma: no cover
-                self._annotate = None
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
 
     def span(self, name: str, **args) -> _Span:
         return _Span(self, name, args)

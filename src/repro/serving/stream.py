@@ -137,7 +137,7 @@ from repro.core.dispatch import (FLAG_ANY_PENDING, FLAG_COLD_FULL,
                                  client_ticket, merge_client_queues,
                                  ticket_client)
 from repro.core.index import (PFOIndex, delete_step, delete_step_cold,
-                              init_state, insert_step, merge_step,
+                              insert_step, merge_step,
                               query_step, query_step_cold, round_flags,
                               seal_step)
 from repro.obs import Obs
@@ -348,21 +348,25 @@ class LocalBackend:
                 jax.block_until_ready(
                     step(idx.state, vecs, cfg, default_k))
         jax.block_until_ready(round_flags(idx.state, cfg, fm, fl))
-        scratch = init_state(cfg, jax.random.PRNGKey(0))
+        # epoch programs run on the live state with their results
+        # discarded (the state is untouched): no second full-size state
+        # is ever allocated, so warmup peaks at one epoch's footprint
+        sealed = seal_step(idx.state, cfg)
         if cold:
-            # compile the spill program against a scratch state so the
-            # first real spill epoch does not pay a jit compile
+            # compile the spill program so the first real spill epoch
+            # does not pay a jit compile
             from repro.core.coldtier import spill_device
             from repro.core.index import (_snap_cfg_lsh, _snap_cfg_main,
                                           main_tree_config)
-            sealed = seal_step(scratch, cfg)
             jax.block_until_ready(spill_device(
                 sealed.lsh_snaps, sealed.main_snaps, sealed.cold,
                 sealed.store, sealed.main_forest, sealed.tombstones,
                 _snap_cfg_lsh(cfg), _snap_cfg_main(cfg),
                 main_tree_config(cfg))[:4])
         else:
-            jax.block_until_ready(merge_step(seal_step(scratch, cfg), cfg))
+            jax.block_until_ready(sealed)
+            del sealed
+            jax.block_until_ready(merge_step(idx.state, cfg))
 
 
 class DistBackend:
@@ -839,18 +843,19 @@ class DistBackend:
                 jax.block_until_ready(
                     self._qry[default_k](self.state, vecs)[:2])
         jax.block_until_ready(self._flags_fn(self.state))
-        scratch = self._dist.dist_init_state(self.dcfg,
-                                             jax.random.PRNGKey(0),
-                                             self.mesh)
+        # epoch programs run on the live state, results discarded (see
+        # LocalBackend.warmup): no second full-size state
+        sealed = self._seal_fn(self.state)
         if self.cold_mgrs is not None:
             # cold rings never merge on device (spill relieves capacity,
             # TOMBS_FULL folds on host) — precompile spill + drain so
             # the first real epoch pays no jit compile
-            sealed = self._seal_fn(scratch)
             jax.block_until_ready(self._spill_fn(sealed)[1])
             jax.block_until_ready(self._drain_fn(sealed)[1])
         else:
-            jax.block_until_ready(self._merge_fn(self._seal_fn(scratch)))
+            jax.block_until_ready(sealed)
+            del sealed
+            jax.block_until_ready(self._merge_fn(self.state))
 
     def stats(self) -> dict:
         st = self.state

@@ -58,3 +58,16 @@ def test_dist_recall_beats_random(dist_setup):
     rec = np.mean([len(set(np.asarray(ids)[i]) & set(oid[i])) / 10
                    for i in range(16)])
     assert rec > 0.1
+
+
+def test_dedup_topk_keeps_k_distinct_ids_across_shard_copies():
+    """Each of the S shards that found a candidate ranks its own copy;
+    with every id present S times the merge still returns k distinct
+    ids (keeping only the 2k nearest entries returned k/2)."""
+    from repro.core.distributed import _dedup_topk
+    S, k = 4, 10
+    ids = np.repeat(np.arange(3 * k, dtype=np.int32), S)
+    d = np.repeat(np.arange(3 * k, dtype=np.float32), S)
+    out_ids, out_d = _dedup_topk(jnp.asarray(ids), jnp.asarray(d), k, S)
+    np.testing.assert_array_equal(np.asarray(out_ids), np.arange(k))
+    np.testing.assert_array_equal(np.asarray(out_d), np.arange(k))

@@ -102,3 +102,85 @@ def test_chain_capped_query_still_terminates():
     ids, vals, n = tree_query(stt, jnp.uint32(0xFFFFFFFF), CFG)
     assert int(n) <= CFG.max_candidates
     assert int(stt.n_items) == 40
+
+
+# ----------------------------------------------------------------------
+# forest_insert_dispatched == a vmap over trees of the per-tree insert
+# ----------------------------------------------------------------------
+def _vmapped_tree_insert(forest, hs, ids, vals, cfg):
+    """The per-tree reference: each tree scans its mailbox through
+    :func:`tree_insert`, trees vmapped."""
+    def per_tree(stt, hs, vids, vals):
+        def step(stt, x):
+            h, vid, val = x
+            stt = jax.lax.cond(vid >= 0,
+                               lambda s: tree_insert(s, h, vid, val, cfg),
+                               lambda s: s, stt)
+            return stt, ()
+        return jax.lax.scan(step, stt, (hs, vids, vals))[0]
+    return jax.vmap(per_tree)(forest, hs, ids, vals)
+
+
+def _mailboxes(rng, kind, n_trees, k, base_id):
+    if kind == "random":
+        keys = rng.integers(0, 2**32, (n_trees, k), dtype=np.uint64)
+    elif kind == "clustered":      # shared prefixes: deep spreads
+        keys = (rng.integers(0, 4, (n_trees, k), dtype=np.uint64) << 28) \
+            | rng.integers(0, 64, (n_trees, k), dtype=np.uint64)
+    else:                          # identical keys: chains at max depth
+        keys = np.full((n_trees, k), 0x5A5A5A5A, np.uint64)
+    ids = base_id + np.arange(n_trees * k, dtype=np.int32).reshape(n_trees, k)
+    ids = np.where(rng.random((n_trees, k)) < 0.15, -1, ids)  # padding
+    return (jnp.asarray(keys.astype(np.uint32)), jnp.asarray(ids),
+            jnp.asarray(ids * 3))
+
+
+@pytest.mark.parametrize("kind", ["random", "clustered", "identical"])
+@pytest.mark.parametrize("max_nodes", [64, 3])
+def test_forest_insert_matches_per_tree_insert(kind, max_nodes):
+    """Flat batched forest insert is field-for-field the vmapped
+    per-tree insert — spreads, chains past ``t`` at max depth, node
+    exhaustion (``max_nodes=3``), padding, and free-list reuse after
+    deletes."""
+    from repro.core.hash_tree import (forest_delete_dispatched,
+                                      forest_insert_dispatched,
+                                      init_forest)
+    cfg = TreeConfig(skip_bits=2, log2_l=4, l=16, t=3, max_depth=4,
+                     max_nodes=max_nodes, max_leaves=256,
+                     max_candidates=16)
+    rng = np.random.default_rng(len(kind) * 7 + max_nodes)
+    n_trees, k = 5, 48
+    got = want = init_forest(cfg, n_trees)
+    for rnd in range(2):
+        hs, ids, vals = _mailboxes(rng, kind, n_trees, k, rnd * 1000)
+        got = forest_insert_dispatched(got, hs, ids, vals, cfg)
+        want = _vmapped_tree_insert(want, hs, ids, vals, cfg)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # delete a third of the round: the next round pops the free list
+        dels = jnp.where(jnp.asarray(rng.random(ids.shape) < 0.33), ids, -1)
+        got = forest_delete_dispatched(got, hs, dels, cfg)
+        want = forest_delete_dispatched(want, hs, dels, cfg)
+
+
+def test_forest_insert_leaf_exhaustion_drops_and_counts():
+    """A full leaf arena drops the record and counts it in ``overflow``;
+    everything that did land stays findable."""
+    from repro.core.hash_tree import (forest_insert_dispatched,
+                                      forest_lookup, init_forest)
+    cfg = TreeConfig(skip_bits=2, log2_l=4, l=16, t=3, max_depth=4,
+                     max_nodes=64, max_leaves=40, max_candidates=64)
+    rng = np.random.default_rng(11)
+    hs, ids, vals = _mailboxes(rng, "clustered", 3, 64, 0)
+    f = forest_insert_dispatched(init_forest(cfg, 3), hs, ids, vals, cfg)
+    n_valid = np.sum(np.asarray(ids) >= 0, axis=1)
+    np.testing.assert_array_equal(np.asarray(f.n_items),
+                                  np.minimum(n_valid, 40))
+    np.testing.assert_array_equal(np.asarray(f.overflow),
+                                  np.maximum(n_valid - 40, 0))
+    landed = np.asarray(f.leaf_id)                      # (T, 40)
+    tids = np.repeat(np.arange(3), 40)
+    keys = np.asarray(f.leaf_key).reshape(-1)
+    _, found = forest_lookup(f, jnp.asarray(tids), jnp.asarray(keys),
+                             jnp.asarray(landed.reshape(-1)), cfg)
+    assert bool(jnp.all(found))
