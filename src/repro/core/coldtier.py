@@ -593,29 +593,9 @@ class ColdManager:
 
     # -- observability --------------------------------------------------
     def set_obs(self, obs) -> None:
-        """Bind an observability handle; cold stats mirror into
-        ``cold.*`` gauges lazily at snapshot time."""
+        """Bind an observability handle (the compaction spans); the
+        tier's counters are read through :meth:`stats`."""
         self.obs = obs
-        obs.on_snapshot("cold", self._mirror_obs)
-
-    def _mirror_obs(self) -> None:
-        g = self.obs.gauge
-        s = self.stats()
-        g("cold.segments").set(s["cold_segments"])
-        g("cold.spills").set(s["segments_spilled"])
-        g("cold.fetches").set(s["fetches"])
-        g("cold.fetch_rounds").set(s["fetch_rounds"])
-        g("cold.fetches_per_query_round").set(s["fetches_per_query_round"])
-        g("cold.incomplete_query_rounds").set(s["incomplete_query_rounds"])
-        g("cold.cache_hit_rate").set(s["cache_hit_rate"])
-        g("cold.bloom_fp_rate").set(s["bloom_fp_rate"])
-        g("cold.compactions").set(s["compactions"])
-        g("cold.merges").set(s["cold_merges"])
-        g("cold.store_bytes_written").set(s["store_bytes_written"])
-        g("cold.vec_staging_hit_rate").set(s["vec_staging_hit_rate"])
-        g("cold.vec_fetch_bytes").set(s["vec_fetch_bytes"])
-        g("cold.vec_evictions").set(s["vec_evictions"])
-        g("cold.vec_resident_pages").set(s["vec_resident_pages"])
 
     @property
     def n_cold(self) -> int:

@@ -1037,29 +1037,3 @@ def make_dist_round_flags(dcfg: DistConfig, mesh: Mesh, flags_main: int,
                           in_specs=(state_pspecs(dcfg),),
                           out_specs=P(), check_vma=False)
     return jax.jit(fn)
-
-
-# ----------------------------------------------------------------------
-# host-side observability (one transfer per field, at snapshot time —
-# never inside a round)
-# ----------------------------------------------------------------------
-def shard_occupancy(state: PFOState, n_shards: int) -> dict:
-    """Aggregate per-shard occupancy counters host-side.
-
-    Reads the small per-tree/per-shard counter arrays (n_items,
-    free_top) back in one gather each and folds them into per-shard
-    totals plus a load-imbalance ratio (max/mean hot items).  Called
-    only from ``stats()``/metrics-snapshot paths, so the serving rounds
-    keep their one-readback invariant.
-    """
-    import numpy as np
-    main = np.asarray(state.main_forest.n_items).reshape(n_shards, -1)
-    lsh = np.asarray(state.lsh_forest.n_items).reshape(n_shards, -1)
-    free = np.asarray(state.store.free_top).reshape(n_shards, -1)
-    items = main.sum(axis=1)
-    return {
-        "items_per_shard": items.tolist(),
-        "lsh_per_shard": lsh.sum(axis=1).tolist(),
-        "store_free_per_shard": free.sum(axis=1).tolist(),
-        "imbalance": float(items.max() / max(float(items.mean()), 1.0)),
-    }

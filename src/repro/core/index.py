@@ -23,7 +23,6 @@ All L LSH tables are stacked into one forest with global tree ids
 from __future__ import annotations
 
 import functools
-import time
 from typing import NamedTuple
 
 import jax
@@ -142,6 +141,7 @@ def _cold_full_threshold(cfg: PFOConfig) -> int:
     return cfg.cold_segments - max(1, cfg.cold_segments // 4)
 
 
+@jax.named_scope("flags")
 def _round_flags(state: PFOState, cfg: PFOConfig, main_capacity: int,
                  lsh_capacity: int, any_pending: jax.Array,
                  cold_miss: jax.Array | None = None) -> jax.Array:
@@ -225,42 +225,45 @@ def insert_step(state: PFOState, ids: jax.Array, vecs: jax.Array,
     one carried flag word stays valid across bucket sizes).
     """
     # --- store allocation (at most once per row) ---------------------
-    need_alloc = (slots_in == -2) & main_active
-    store, new_slots, alloc_ok = dense_alloc(state.store, vecs, need_alloc)
-    slots = jnp.where(need_alloc & alloc_ok, new_slots, slots_in)
-    state = state._replace(store=store)
-    have_slot = slots >= 0
-
-    # re-inserting a previously-deleted id revokes its tombstone (the
-    # fresh hot MainTable entry shadows any stale sealed copies)
-    revived = member_sorted(state.tombstones,
-                            jnp.where(main_active, ids, -1))
-    state = state._replace(
-        tombstones=jnp.where(revived, -1, state.tombstones))
+    with jax.named_scope("store_alloc"):
+        need_alloc = (slots_in == -2) & main_active
+        store, new_slots, alloc_ok = dense_alloc(state.store, vecs,
+                                                 need_alloc)
+        slots = jnp.where(need_alloc & alloc_ok, new_slots, slots_in)
+        state = state._replace(store=store)
+        have_slot = slots >= 0
 
     # --- MainTable insert --------------------------------------------
-    mh, mtree = main_table_keys(ids, cfg)
-    m_req = jnp.where(main_active & have_slot, mtree, -1)
-    mbox, m_ovf = dispatch_to_trees(m_req, cfg.main_n_trees,
-                                    main_capacity)
-    (mh_g,) = gather_mailbox(mbox, mh)
-    mid_g = mailbox_ids(mbox, ids)
-    (mval_g,) = gather_mailbox(mbox, slots)
-    main_forest = forest_insert_dispatched(
-        state.main_forest, mh_g, mid_g, mval_g, main_tree_config(cfg))
+    with jax.named_scope("main_insert"):
+        # re-inserting a previously-deleted id revokes its tombstone
+        # (the fresh hot MainTable entry shadows any stale sealed copies)
+        revived = member_sorted(state.tombstones,
+                                jnp.where(main_active, ids, -1))
+        state = state._replace(
+            tombstones=jnp.where(revived, -1, state.tombstones))
+        mh, mtree = main_table_keys(ids, cfg)
+        m_req = jnp.where(main_active & have_slot, mtree, -1)
+        mbox, m_ovf = dispatch_to_trees(m_req, cfg.main_n_trees,
+                                        main_capacity)
+        (mh_g,) = gather_mailbox(mbox, mh)
+        mid_g = mailbox_ids(mbox, ids)
+        (mval_g,) = gather_mailbox(mbox, slots)
+        main_forest = forest_insert_dispatched(
+            state.main_forest, mh_g, mid_g, mval_g, main_tree_config(cfg))
 
     # --- LSHTables insert ---------------------------------------------
-    h, gtrees = compute_keys(state, vecs, cfg)                   # (N, L)
-    flat_h = h.reshape(-1)
-    flat_id = jnp.repeat(ids, cfg.L)
-    l_req = jnp.where(lsh_active & jnp.repeat(have_slot, cfg.L),
-                      gtrees.reshape(-1), -1)
-    lbox, l_ovf = dispatch_to_trees(l_req, cfg.L * cfg.n_trees,
-                                    lsh_capacity)
-    (lh_g,) = gather_mailbox(lbox, flat_h)
-    lid_g = mailbox_ids(lbox, flat_id)
-    lsh_forest = forest_insert_dispatched(
-        state.lsh_forest, lh_g, lid_g, lid_g, lsh_tree_config(cfg))
+    with jax.named_scope("lsh_insert"):
+        h, gtrees = compute_keys(state, vecs, cfg)               # (N, L)
+        flat_h = h.reshape(-1)
+        flat_id = jnp.repeat(ids, cfg.L)
+        l_req = jnp.where(lsh_active & jnp.repeat(have_slot, cfg.L),
+                          gtrees.reshape(-1), -1)
+        lbox, l_ovf = dispatch_to_trees(l_req, cfg.L * cfg.n_trees,
+                                        lsh_capacity)
+        (lh_g,) = gather_mailbox(lbox, flat_h)
+        lid_g = mailbox_ids(lbox, flat_id)
+        lsh_forest = forest_insert_dispatched(
+            state.lsh_forest, lh_g, lid_g, lid_g, lsh_tree_config(cfg))
 
     state = state._replace(main_forest=main_forest, lsh_forest=lsh_forest)
 
@@ -315,6 +318,7 @@ def merge_step(state: PFOState, cfg: PFOConfig) -> PFOState:
         n_tombstones=jnp.int32(0))
 
 
+@jax.named_scope("main_lookup")
 def _main_lookup(state: PFOState, ids: jax.Array, cfg: PFOConfig):
     """(N,) id -> (slot, found), searching hot forest then sealed tier."""
     mh, mtree = main_table_keys(ids, cfg)
@@ -333,20 +337,25 @@ def _hot_sealed_candidates(state: PFOState, qvecs: jax.Array,
     parallel, probe the sealed ring Bloom-first (newest segments
     first).  Returns (h (Q, L), cand (Q, L*mc + L*S*P*B))."""
     q = qvecs.shape[0]
-    h, gtrees = compute_keys(state, qvecs, cfg)                  # (Q, L)
-    flat_ids, _, _ = forest_query(state.lsh_forest, gtrees.reshape(-1),
-                                  h.reshape(-1), lsh_tree_config(cfg))
-    hot = flat_ids.reshape(q, -1)                                # (Q, L*mc)
+    with jax.named_scope("hash"):
+        h, gtrees = compute_keys(state, qvecs, cfg)              # (Q, L)
+    with jax.named_scope("hot_descent"):
+        flat_ids, _, _ = forest_query(state.lsh_forest,
+                                      gtrees.reshape(-1), h.reshape(-1),
+                                      lsh_tree_config(cfg))
+        hot = flat_ids.reshape(q, -1)                            # (Q, L*mc)
 
     def per_table(snaps_l, h_l):
         cids, _ = snap_mod.probe(snaps_l, h_l, _snap_cfg_lsh(cfg))
         return cids                                              # (Q, S*P*B)
 
-    sealed = jax.vmap(per_table, in_axes=(0, 1), out_axes=1)(
-        state.lsh_snaps, h)                                      # (Q, L, ·)
-    return h, jnp.concatenate([hot, sealed.reshape(q, -1)], axis=1)
+    with jax.named_scope("sealed_probe"):
+        sealed = jax.vmap(per_table, in_axes=(0, 1), out_axes=1)(
+            state.lsh_snaps, h)                                  # (Q, L, ·)
+        return h, jnp.concatenate([hot, sealed.reshape(q, -1)], axis=1)
 
 
+@jax.named_scope("dedupe")
 def _dedupe_candidates(cand: jax.Array, tombstones: jax.Array,
                        cfg: PFOConfig) -> jax.Array:
     """Tombstone filter + dedupe + truncate to the ranking budget:
@@ -362,6 +371,7 @@ def _dedupe_candidates(cand: jax.Array, tombstones: jax.Array,
     return jnp.where(uniq == INT_MAX, -1, uniq)
 
 
+@jax.named_scope("rank")
 def _rank_candidates(state: PFOState, qvecs: jax.Array, cids: jax.Array,
                      slot: jax.Array, found: jax.Array, cfg: PFOConfig,
                      k: int, staging: jax.Array | None = None):
@@ -409,6 +419,7 @@ def _staging_arena(state: PFOState, cfg: PFOConfig) -> jax.Array | None:
     return vecs.reshape(-1, vecs.shape[-1])
 
 
+@jax.named_scope("main_lookup")
 def _main_lookup_cold(state: PFOState, ids: jax.Array, cfg: PFOConfig,
                       active: jax.Array | None = None):
     """(N,) id -> (slot, found, unresolved, wanted, missing, probed, fp).
@@ -499,44 +510,52 @@ def _delete_apply(state: PFOState, ids: jax.Array, slot: jax.Array,
     store slot is NOT freed (the spill already freed it — freeing the
     out-of-range encoded slot would push garbage on the free stack).
     """
-    # re-derive LSH keys from the stored vector
-    vecs = dense_read_tiered(state.store, staging, jnp.where(ok, slot, 0))
-    h, gtrees = compute_keys(state, vecs, cfg)
-    flat_tree = jnp.where(jnp.repeat(ok, cfg.L), gtrees.reshape(-1), -1)
-    flat_id = jnp.repeat(ids, cfg.L)
-    lbox, l_ovf = dispatch_to_trees(flat_tree, cfg.L * cfg.n_trees,
-                                    lsh_capacity)
-    (lh_g,) = gather_mailbox(lbox, h.reshape(-1))
-    lid_g = mailbox_ids(lbox, flat_id)
-    lsh_forest = forest_delete_dispatched(state.lsh_forest, lh_g, lid_g,
-                                          lsh_tree_config(cfg))
+    with jax.named_scope("lsh_unlink"):
+        # re-derive LSH keys from the stored vector
+        vecs = dense_read_tiered(state.store, staging,
+                                 jnp.where(ok, slot, 0))
+        h, gtrees = compute_keys(state, vecs, cfg)
+        flat_tree = jnp.where(jnp.repeat(ok, cfg.L), gtrees.reshape(-1),
+                              -1)
+        flat_id = jnp.repeat(ids, cfg.L)
+        lbox, l_ovf = dispatch_to_trees(flat_tree, cfg.L * cfg.n_trees,
+                                        lsh_capacity)
+        (lh_g,) = gather_mailbox(lbox, h.reshape(-1))
+        lid_g = mailbox_ids(lbox, flat_id)
+        lsh_forest = forest_delete_dispatched(state.lsh_forest, lh_g,
+                                              lid_g, lsh_tree_config(cfg))
 
-    mh, mtree = main_table_keys(ids, cfg)
-    mbox, m_ovf = dispatch_to_trees(jnp.where(ok, mtree, -1),
-                                    cfg.main_n_trees, main_capacity)
-    (mh_g,) = gather_mailbox(mbox, mh)
-    mid_g = mailbox_ids(mbox, ids)
-    main_forest = forest_delete_dispatched(state.main_forest, mh_g, mid_g,
-                                           main_tree_config(cfg))
+    with jax.named_scope("main_unlink"):
+        mh, mtree = main_table_keys(ids, cfg)
+        mbox, m_ovf = dispatch_to_trees(jnp.where(ok, mtree, -1),
+                                        cfg.main_n_trees, main_capacity)
+        (mh_g,) = gather_mailbox(mbox, mh)
+        mid_g = mailbox_ids(mbox, ids)
+        main_forest = forest_delete_dispatched(state.main_forest, mh_g,
+                                               mid_g, main_tree_config(cfg))
 
-    if staging is None:
-        store = dense_free(state.store, slot, ok)
-    else:
-        hot_ok = ok & (slot < cfg.store_capacity)
-        store = dense_free(state.store, jnp.where(hot_ok, slot, 0), hot_ok)
+    with jax.named_scope("store_free"):
+        if staging is None:
+            store = dense_free(state.store, slot, ok)
+        else:
+            hot_ok = ok & (slot < cfg.store_capacity)
+            store = dense_free(state.store, jnp.where(hot_ok, slot, 0),
+                               hot_ok)
 
     # tombstones cover sealed copies; overflow rows stay pending.
     # Overflow writes park out of bounds (dropped by XLA) — clamping
     # them to the last slot would clobber the tombstone legitimately
     # written there in the same scatter.
-    want = ok.astype(jnp.int32)
-    rank = jnp.cumsum(want) - want
-    pos = state.n_tombstones + rank
-    fits = ok & (pos < cfg.max_tombstones)
-    safe = jnp.where(fits, pos, cfg.max_tombstones)
-    tombs = state.tombstones.at[safe].set(ids, mode="drop")
-    n_t = jnp.minimum(state.n_tombstones + jnp.sum(fits.astype(jnp.int32)),
-                      cfg.max_tombstones)
+    with jax.named_scope("tombstone"):
+        want = ok.astype(jnp.int32)
+        rank = jnp.cumsum(want) - want
+        pos = state.n_tombstones + rank
+        fits = ok & (pos < cfg.max_tombstones)
+        safe = jnp.where(fits, pos, cfg.max_tombstones)
+        tombs = state.tombstones.at[safe].set(ids, mode="drop")
+        n_t = jnp.minimum(
+            state.n_tombstones + jnp.sum(fits.astype(jnp.int32)),
+            cfg.max_tombstones)
 
     state = state._replace(lsh_forest=lsh_forest, main_forest=main_forest,
                            store=store, tombstones=tombs, n_tombstones=n_t)
@@ -656,8 +675,8 @@ class PFOIndex:
 
     # -- observability --------------------------------------------------
     def set_obs(self, obs: Obs) -> None:
-        """Bind an observability handle; the index's counters mirror
-        into gauges lazily at snapshot time (``repro.obs``), and the
+        """Bind an observability handle; the readback count mirrors
+        into a gauge lazily at snapshot time (``repro.obs``), and the
         cold manager inherits the same handle."""
         self.obs = obs
         obs.on_snapshot("index", self._mirror_obs)
@@ -665,19 +684,12 @@ class PFOIndex:
             self.cold.set_obs(obs)
 
     def _mirror_obs(self) -> None:
-        o = self.obs
-        o.gauge("index.readbacks").set(self.sync_count)
-        o.gauge("index.items_inserted").set(self.n_inserted)
+        self.obs.gauge("index.readbacks").set(self.sync_count)
 
     def _epoch(self, name: str, fn, *args):
-        """Run one maintenance epoch under a span + its latency
-        histogram (``index.maint_ms{epoch=...}``)."""
-        t0 = time.perf_counter()
+        """Run one maintenance epoch under a span."""
         with self.obs.span(name):
-            out = fn(*args)
-        self.obs.histogram("index.maint_ms", epoch=name).observe(
-            (time.perf_counter() - t0) * 1e3)
-        return out
+            return fn(*args)
 
     # -- capacity heuristics -------------------------------------------
     def _lsh_capacity(self, n: int) -> int:
@@ -777,7 +789,6 @@ class PFOIndex:
         main_active = jnp.ones((n,), bool)
         lsh_active = jnp.ones((n * self.cfg.L,), bool)
         lcap, mcap = self._lsh_capacity(n), self._main_capacity(n)
-        t0 = time.perf_counter()
         with self.obs.span("insert", n=n):
             flags = self._ensure_flags(mcap, lcap)
             rounds = 0
@@ -790,23 +801,18 @@ class PFOIndex:
                 flags = self._read_flags(fw, (mcap, lcap))
                 if not flags & FLAG_ANY_PENDING:
                     break
-        self.obs.histogram("index.op_ms", op="insert").observe(
-            (time.perf_counter() - t0) * 1e3)
         self.n_inserted += n
         self.rounds_log.append(rounds)
         return rounds
 
     def query(self, qvecs, k: int = 10):
         qvecs = jnp.asarray(qvecs, jnp.float32)
-        t0 = time.perf_counter()
         with self.obs.span("query", n=int(qvecs.shape[0]), k=k):
             if self.cold is None:
                 ids, dists = query_step(self.state, qvecs, self.cfg, k)
                 ids, dists = jax.device_get((ids, dists))
             else:
                 ids, dists = self._query_cold(qvecs, k)
-        self.obs.histogram("index.op_ms", op="query").observe(
-            (time.perf_counter() - t0) * 1e3)
         return np.asarray(ids), np.asarray(dists)
 
     def _query_cold(self, qvecs, k: int, overlap=None):
@@ -849,7 +855,6 @@ class PFOIndex:
         active = jnp.ones(ids.shape, bool)
         n = int(ids.shape[0])
         lcap, mcap = self._lsh_capacity(n), self._main_capacity(n)
-        t0 = time.perf_counter()
         with self.obs.span("delete", n=n):
             flags = self._ensure_flags(mcap, lcap)
             rounds = 0
@@ -868,8 +873,6 @@ class PFOIndex:
                 if not flags & FLAG_ANY_PENDING:
                     break
                 active = pending
-        self.obs.histogram("index.op_ms", op="delete").observe(
-            (time.perf_counter() - t0) * 1e3)
         return rounds
 
     def fetch_delete_miss(self, flags: int) -> None:

@@ -76,6 +76,7 @@ def probe_prefixes(hs: jax.Array, cfg: PFOConfig) -> jax.Array:
     return pfx[:, None] ^ jnp.arange(cfg.snap_probes, dtype=jnp.uint32)
 
 
+@jax.named_scope("reseal")
 def seal(snaps: SnapshotSet, keys: jax.Array, ids: jax.Array,
          vals: jax.Array, mask: jax.Array, stamp: jax.Array,
          cfg: PFOConfig) -> SnapshotSet:
@@ -245,31 +246,37 @@ def merge(snaps: SnapshotSet, cfg: PFOConfig,
     ids = snaps.ids.reshape(-1)
     vals = snaps.vals.reshape(-1)
     rank = seg_rank.reshape(-1)
-    live = ids >= 0
-    if deleted_ids is not None and deleted_ids.shape[0] > 0:
-        dead = member_sorted(ids, deleted_ids)
-        live = live & ~dead
+    with jax.named_scope("merge_filter"):
+        live = ids >= 0
+        if deleted_ids is not None and deleted_ids.shape[0] > 0:
+            dead = member_sorted(ids, deleted_ids)
+            live = live & ~dead
 
-    # newest (highest stamp) version of an id wins
-    ikey = jnp.where(live, ids, jnp.int32(2**31 - 1))
-    gkey = jnp.where(live, vals, 0) if group_by_val else jnp.zeros_like(ids)
-    order = jnp.lexsort((-rank, ikey, gkey))
-    sids = jnp.where(live[order], ids[order], -1)
-    sgrp = gkey[order]
-    first_of_id = jnp.concatenate(
-        [jnp.array([True]),
-         (sids[1:] != sids[:-1]) | (sgrp[1:] != sgrp[:-1])]) & (sids >= 0)
+    with jax.named_scope("merge_sort"):
+        # newest (highest stamp) version of an id wins
+        ikey = jnp.where(live, ids, jnp.int32(2**31 - 1))
+        gkey = jnp.where(live, vals, 0) if group_by_val \
+            else jnp.zeros_like(ids)
+        order = jnp.lexsort((-rank, ikey, gkey))
+        sids = jnp.where(live[order], ids[order], -1)
+        sgrp = gkey[order]
+        first_of_id = jnp.concatenate(
+            [jnp.array([True]),
+             (sids[1:] != sids[:-1]) | (sgrp[1:] != sgrp[:-1])]) \
+            & (sids >= 0)
 
-    keep_keys = jnp.where(first_of_id, keys[order], _PAD_KEY)
-    keep_ids = jnp.where(first_of_id, sids, -1)
-    keep_vals = jnp.where(first_of_id, vals[order], 0)
+        keep_keys = jnp.where(first_of_id, keys[order], _PAD_KEY)
+        keep_ids = jnp.where(first_of_id, sids, -1)
+        keep_vals = jnp.where(first_of_id, vals[order], 0)
 
-    merged = init_snapshots(cfg)
-    take = min(cap, keep_keys.shape[0])
-    # Keep at most one segment's worth (overflow counted for observability).
-    korder = jnp.argsort(jnp.where(keep_ids >= 0, jnp.uint32(0), jnp.uint32(1)))
-    keep_keys, keep_ids, keep_vals = (keep_keys[korder][:take],
-                                      keep_ids[korder][:take],
-                                      keep_vals[korder][:take])
+        merged = init_snapshots(cfg)
+        take = min(cap, keep_keys.shape[0])
+        # Keep at most one segment's worth (overflow counted for
+        # observability).
+        korder = jnp.argsort(jnp.where(keep_ids >= 0, jnp.uint32(0),
+                                       jnp.uint32(1)))
+        keep_keys, keep_ids, keep_vals = (keep_keys[korder][:take],
+                                          keep_ids[korder][:take],
+                                          keep_vals[korder][:take])
     return seal(merged, keep_keys, keep_ids, keep_vals, keep_ids >= 0,
                 jnp.max(snaps.stamps), cfg)

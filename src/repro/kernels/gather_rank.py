@@ -120,7 +120,8 @@ def _kernel_staged(slots_ref, q_ref, valid_ref, store_ref, staging_ref,
     _rank(q_ref, valid_ref, out_ref, buf_ref, c=c, angular=angular)
 
 
-def _call(kernel, q, slots, valid, arenas, *, bq: int, interpret: bool):
+def _call(kernel, name, q, slots, valid, arenas, *, bq: int,
+          interpret: bool):
     nq, dim = q.shape
     nq2, c = slots.shape
     assert nq == nq2 and slots.shape == valid.shape
@@ -142,6 +143,7 @@ def _call(kernel, q, slots, valid, arenas, *, bq: int, interpret: bool):
         scratch_shapes=[pltpu.VMEM((bq * c, dim), jnp.float32),
                         pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name=name,
     )(slots.reshape(-1), q, valid, *arenas)
 
 
@@ -159,7 +161,7 @@ def gather_rank_pallas(q: jax.Array, store: jax.Array, slots: jax.Array,
     """
     kernel = functools.partial(_kernel, n_rows=store.shape[0],
                                c=slots.shape[1], angular=angular)
-    return _call(kernel, q, slots, valid, [store], bq=bq,
+    return _call(kernel, "gather_rank", q, slots, valid, [store], bq=bq,
                  interpret=interpret)
 
 
@@ -176,5 +178,5 @@ def gather_rank_staged_pallas(q: jax.Array, store: jax.Array,
     kernel = functools.partial(_kernel_staged, n_rows=store.shape[0],
                                n_staging=staging.shape[0],
                                c=slots.shape[1], angular=angular)
-    return _call(kernel, q, slots, valid, [store, staging], bq=bq,
-                 interpret=interpret)
+    return _call(kernel, "gather_rank_staged", q, slots, valid,
+                 [store, staging], bq=bq, interpret=interpret)

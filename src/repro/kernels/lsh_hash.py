@@ -100,4 +100,5 @@ def lsh_hash_pallas(x: jax.Array, a: jax.Array, pack_hi: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n, w), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bn, p), jnp.float32)],
         interpret=interpret,
+        name="lsh_hash",
     )(x, a, pack_hi, pack_lo)

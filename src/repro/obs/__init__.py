@@ -14,6 +14,12 @@ through the engine stack (``PFOIndex`` -> ``LocalBackend`` ->
   as Chrome/Perfetto ``trace_event`` JSON.  Off by default; when off a
   span costs ONE branch returning a shared no-op context manager.
 
+* **compiles** — every live handle with metrics or tracing on counts
+  the process's XLA compiles (``jit.compiles``; a program loaded from
+  the persistent compilation cache counts too) through one
+  ``jax.monitoring`` listener, and when tracing records each as a
+  ``compile`` span.  A serving window after warm-up should count none.
+
 The hard invariant (tested under the JAX transfer guard): recording a
 metric or span never touches a ``jax.Array`` — tracing adds ZERO
 device readbacks to a steady-state serving round.
@@ -23,12 +29,39 @@ Metric names and the trace-event schema are documented in
 """
 from __future__ import annotations
 
+import time
 import warnings
+import weakref
 
 from . import report
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       NULL_METRIC, render_name)
 from .trace import NULL_SPAN, NULL_TRACER, NullTracer, Tracer
+
+
+#: the ``jax.monitoring`` duration event around each XLA compile
+#: (persistent-cache loads included)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_sinks: "weakref.WeakSet[Obs]" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _on_duration(event: str, duration_secs: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        for obs in list(_compile_sinks):
+            obs._on_compile(duration_secs)
+
+
+def _count_compiles(obs: "Obs") -> None:
+    """Subscribe ``obs`` to the process's compiles; the one listener is
+    registered on first use, and a collected handle drops out."""
+    global _compile_listening
+    if not _compile_listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listening = True
+    _compile_sinks.add(obs)
 
 
 class Obs:
@@ -45,6 +78,15 @@ class Obs:
             # a truncated trace is never silently misread
             self.on_snapshot("trace", lambda: self.gauge(
                 "obs.trace_dropped").set(self.tracer.dropped))
+        if metrics or trace:
+            self._c_compiles = self.counter("jit.compiles")
+            _count_compiles(self)
+
+    def _on_compile(self, duration_secs: float) -> None:
+        self._c_compiles.inc()
+        if self.tracer.enabled:
+            t1 = time.perf_counter_ns()
+            self.tracer.record("compile", t1 - int(duration_secs * 1e9), t1)
 
     # -- capability flags (hot-path guards) -----------------------------
     @property

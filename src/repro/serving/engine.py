@@ -17,7 +17,6 @@ rather than a sidecar.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -97,13 +96,10 @@ class ServingEngine:
     # -- kNN-LM ----------------------------------------------------------
     def _knn_logits(self, hidden: np.ndarray, vocab: int) -> np.ndarray:
         """hidden (B, D) -> (B, V) kNN distribution (log space)."""
-        t0 = time.perf_counter()
         with self.obs.span("knn", batch=int(hidden.shape[0])):
             tickets = [self.stream.query(hidden[b], k=self.scfg.knn_k)
                        for b in range(hidden.shape[0])]
             res = self.stream.flush()
-        self.obs.histogram("serving.knn_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
         ids = np.stack([res[t][0] for t in tickets])
         dists = np.stack([res[t][1] for t in tickets])
         logits = np.full((hidden.shape[0], vocab), -1e30, np.float32)
@@ -142,25 +138,20 @@ class ServingEngine:
             (cfg.frontend_len if cfg.frontend == "patch" else 0)
         cache = self.model.init_cache(b, total)
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        t0 = time.perf_counter()
         with self.obs.span("prefill", batch=b, prompt_len=prompt_len):
             logits, cache = self.prefill_step(self.params, batch, cache)
 
             # tap the prefill-final hidden for the kNN head
             hid, _ = self.model.forward(self.params, batch)
             last_hidden = np.asarray(hid[:, -1].astype(jnp.float32))
-        self.obs.histogram("serving.prefill_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
 
         out = np.zeros((b, max_new), np.int32)
         pos = prompt_len + (cfg.frontend_len
                             if cfg.frontend == "patch" else 0)
         tok = self._next_token(np.asarray(logits[:, 0]), last_hidden)
         mem_h, mem_t = [last_hidden], [tok]
-        h_decode = self.obs.histogram("serving.decode_step_ms")
         for i in range(max_new):
             out[:, i] = tok
-            t0 = time.perf_counter()
             with self.obs.span("decode", step=i):
                 logits, cache = self.decode_step(
                     self.params, jnp.asarray(tok[:, None]), cache,
@@ -168,8 +159,6 @@ class ServingEngine:
                 # hidden for the kNN head: logits are enough for argmax;
                 # reuse unembedded last layer via logits tap (approx: skip)
                 tok = self._next_token(np.asarray(logits[:, 0]), None)
-            h_decode.observe((time.perf_counter() - t0) * 1e3)
-        self.obs.counter("serving.tokens_generated").inc(b * max_new)
         stats = {"prompt_len": prompt_len, "generated": max_new}
 
         if insert_online and self.stream is not None:
@@ -180,7 +169,6 @@ class ServingEngine:
             for r in range(b):
                 self.stream.insert(int(ids[r]), mem_h[0][r])
             self.stream.flush()
-            self.obs.counter("serving.datastore_inserts").inc(b)
             if self.knn_vocab_map is not None:
                 need = base + b
                 if self.knn_vocab_map.shape[0] < need:

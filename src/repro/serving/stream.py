@@ -79,7 +79,12 @@ end-to-end latency into three host-clock phases::
                                                result pickup/flag ack)
 
 All four are plain host histograms — the accounting adds ZERO device
-readbacks to a round (transfer-guard tested with it enabled).  Clients
+readbacks to a round (transfer-guard tested with it enabled).  With
+tracing on, the flush also records each request's exact phases as
+spans on the tracer (``req_queue``, ``req_batch``, ``req_service``,
+``req_hold``: the last runs from its batch's completion to the flush's
+return), sharing the ticket as id and naming the round's ``dispatch``
+span as parent.  Clients
 opened with ``client(deadline_ms=...)`` join that bound's **deadline
 class**: completions feed ``slo.requests`` / ``slo.violations``
 counters and snapshot-time burn-rate gauges (``repro.obs.slo``), and a
@@ -497,41 +502,17 @@ class DistBackend:
 
     # -- observability --------------------------------------------------
     def set_obs(self, obs: Obs) -> None:
-        """Bind an observability handle; per-shard counters aggregate
-        host-side, lazily, at snapshot time (``dist.*`` gauges)."""
+        """Bind an observability handle; the readback count mirrors
+        into a gauge lazily at snapshot time."""
         self.obs = obs
         obs.on_snapshot("dist", self._mirror_obs)
 
     def _mirror_obs(self) -> None:
-        g = self.obs.gauge
-        g("index.readbacks").set(self.sync_count)
-        g("dist.shards").set(self.dcfg.n_model)
-        # snapshot-time-only device readbacks (documented in obs README)
-        g("dist.query_candidate_drops").set(
-            int(jax.device_get(self._query_drops)))
-        occ = self._dist.shard_occupancy(self.state, self.dcfg.n_model)
-        g("dist.shard_imbalance").set(occ["imbalance"])
-        for s, v in enumerate(occ["items_per_shard"]):
-            g("dist.items_hot", shard=s).set(v)
-        if self.cold_mgrs is not None:
-            cs = self.cold_stats()
-            g("cold.segments").set(cs["cold_segments"])
-            g("cold.spills").set(cs["segments_spilled"])
-            g("cold.fetches").set(cs["fetches"])
-            g("cold.cache_hit_rate").set(cs["cache_hit_rate"])
-            g("cold.vec_staging_hit_rate").set(
-                cs["vec_staging_hit_rate"])
-            g("cold.merges").set(cs["cold_merges"])
-            for s, mgr in enumerate(self.cold_mgrs):
-                g("cold.segments", shard=s).set(mgr.n_cold)
+        self.obs.gauge("index.readbacks").set(self.sync_count)
 
     def _epoch(self, name: str, fn, *args):
-        t0 = time.perf_counter()
         with self.obs.span(name):
-            out = fn(*args)
-        self.obs.histogram("index.maint_ms", epoch=name).observe(
-            (time.perf_counter() - t0) * 1e3)
-        return out
+            return fn(*args)
 
     def maintain(self, flags: int) -> None:
         if flags & FLAG_NEED_SEAL:
@@ -996,13 +977,9 @@ class StreamEngine:
 
     def _bind_obs(self) -> None:
         o = self.obs = self.backend.obs
-        self._obs_on = o.enabled
-        self._h_round = {k: o.histogram("stream.round_ms", kind=k)
-                         for k in (QUERY, INSERT, DELETE, UPDATE)}
-        self._h_flush = o.histogram("stream.flush_ms")
-        self._h_fill = o.histogram("stream.batch_fill")
-        self._h_bucket = o.histogram("stream.bucket_rows")
-        self._g_queue = o.gauge("stream.queue_depth")
+        self._obs_on = o.active
+        self._tracing = o.tracing
+        self._req_spans: list = []     # this flush's rounds, traced only
         # request-grain lifecycle histograms (module docstring): e2e is
         # per kind; the decomposition shares one histogram each so the
         # metric count stays flat
@@ -1023,14 +1000,9 @@ class StreamEngine:
         o = self.obs
         o.gauge("stream.requests").set(self.n_requests)
         o.gauge("stream.flushes").set(self.n_flushes)
-        o.gauge("stream.batches").set(self.n_batches)
         o.gauge("stream.rounds").set(self.n_rounds)
         for k, v in self.n_rounds_by_kind.items():
             o.gauge("stream.rounds", kind=k).set(v)
-        o.gauge("stream.clients").set(1 + len(self._clients))
-        for ev in ("seal", "merge", "spill"):
-            o.gauge("stream.epochs", kind=ev).set(
-                sum(1 for e, _ in self.events if e == ev))
 
     # ------------------------------------------------------------------
     # warmup: precompile every (op, bucket) variant + maintenance steps
@@ -1104,7 +1076,6 @@ class StreamEngine:
         processed by this flush.  ``window`` ordering applies the
         window's updates first (in order), then all queries; ``strict``
         keeps exact submission order (see module docstring)."""
-        self._g_queue.set(self.pending())
         queue = self._ingest()
         t0 = time.perf_counter()
         self._t_flush = t0                # queue_wait / batch_wait pivot
@@ -1126,7 +1097,8 @@ class StreamEngine:
             while len(self._results) > self.scfg.max_retained_results:
                 self._results.pop(next(iter(self._results)))  # oldest first
             self.n_flushes += 1
-        self._h_flush.observe((time.perf_counter() - t0) * 1e3)
+        if self._req_spans:
+            self._record_requests(t0, time.perf_counter())
         return out
 
     def _drain_updates_coalesced(self, updates: list, out: dict) -> None:
@@ -1214,9 +1186,6 @@ class StreamEngine:
         with self.obs.span("pack", kind=kind):
             packed = self._pack(kind, *chunks[0])
         for i, (chunk, bucket) in enumerate(chunks):
-            if self._obs_on:
-                self._h_fill.observe(len(chunk) / bucket)
-                self._h_bucket.observe(bucket)
             # double-buffer hook: the batch methods call this between
             # their first device dispatch and the first (blocking)
             # flag/result readback, so batch t+1's host packing hides
@@ -1231,22 +1200,25 @@ class StreamEngine:
                         hold["p"] = self._pack(kind, *nxt)
 
             t_disp = time.perf_counter()
+            # ``disp``: id of the chunk's first dispatch span (traced)
             if kind == QUERY:
-                self._query_batch(packed, chunk, bucket, out, overlap)
+                disp = self._query_batch(packed, chunk, bucket, out,
+                                         overlap)
             elif kind == INSERT:
-                self._insert_batch(packed, chunk, bucket, out,
-                                   INSERT, overlap)
+                disp = self._insert_batch(packed, chunk, bucket, out,
+                                          INSERT, overlap)
             elif kind == DELETE:
-                self._delete_batch(packed, chunk, bucket, out,
-                                   DELETE, overlap)
+                disp = self._delete_batch(packed, chunk, bucket, out,
+                                          DELETE, overlap)
             else:                                           # UPDATE
-                self._delete_batch(packed["del"], chunk, bucket, None,
-                                   UPDATE, overlap)
+                disp = self._delete_batch(packed["del"], chunk, bucket,
+                                          None, UPDATE, overlap)
                 self._insert_batch(packed["ins"], chunk, bucket, out,
                                    UPDATE, None)
             self.n_batches += 1
             if self._obs_on:
-                self._account(chunk, kind, t_disp, time.perf_counter())
+                self._account(chunk, kind, t_disp, time.perf_counter(),
+                              disp)
             if i + 1 < len(chunks):
                 packed = hold.get("p")
                 if packed is None:
@@ -1260,7 +1232,9 @@ class StreamEngine:
     # construction
     # ------------------------------------------------------------------
     def _account(self, chunk: list, kind: str, t_disp: float,
-                 t_done: float) -> None:
+                 t_done: float, disp) -> None:
+        if self._tracing:
+            self._req_spans.append((chunk, t_disp, t_done, disp))
         h_e2e = self._h_e2e[kind]
         t_flush = self._t_flush
         batch_wait_ms = (t_disp - t_flush) * 1e3
@@ -1277,6 +1251,27 @@ class StreamEngine:
                 dl = deadlines.get(ticket_client(req[0]))
                 if dl is not None:
                     self._slo.observe(dl, e2e_ms)
+
+    def _record_requests(self, t_flush: float, t_ret: float) -> None:
+        """Traced only: four spans per request answered by this flush,
+        ``req_queue`` (arrival -> flush start), ``req_batch`` (-> its
+        round's dispatch), ``req_service`` (-> result pickup or last
+        flag readback) and ``req_hold`` (-> the flush's return).  They
+        tile the request's latency; each takes the ticket as its id and
+        the round's first ``dispatch`` span as its parent."""
+        f0, f1 = int(t_flush * 1e9), int(t_ret * 1e9)
+        spans = []
+        for chunk, t_disp, t_done, disp in self._req_spans:
+            d0, d1 = int(t_disp * 1e9), int(t_done * 1e9)
+            for ticket, kind, _, t_enq in chunk:
+                args = {"kind": kind}
+                spans += (("req_queue", int(t_enq * 1e9), f0, ticket, disp,
+                           args),
+                          ("req_batch", f0, d0, ticket, disp, args),
+                          ("req_service", d0, d1, ticket, disp, args),
+                          ("req_hold", d1, f1, ticket, disp, args))
+        self.obs.tracer.record_many(spans)
+        self._req_spans.clear()
 
     # ------------------------------------------------------------------
     # host-side batch packing (the half that double-buffers)
@@ -1315,24 +1310,23 @@ class StreamEngine:
             self.events.append((ev, self.n_flushes))
 
     def _query_batch(self, packed, chunk: list, bucket: int, out: dict,
-                     overlap=None) -> None:
+                     overlap=None) -> int | None:
         q_d, k = packed
-        t0 = time.perf_counter()
         # the backend invokes overlap() itself, right after its first
         # device dispatch (the cold fetch loop would otherwise block to
         # completion before the engine could start packing batch t+1)
-        with self.obs.span("dispatch", kind=QUERY, bucket=bucket):
+        with self.obs.span("dispatch", kind=QUERY, bucket=bucket,
+                           rows=len(chunk)) as disp:
             ids, dists = self.backend.query_rows(q_d, k, overlap=overlap)
         self.n_rounds_by_kind[QUERY] += 1
         with self.obs.span("result_pickup", kind=QUERY):
             ids, dists = jax.device_get((ids, dists))
-        if self._obs_on:
-            self._h_round[QUERY].observe((time.perf_counter() - t0) * 1e3)
         for r, (ticket, _, _, _) in enumerate(chunk):
             out[ticket] = (ids[r], dists[r])
+        return disp.id
 
     def _insert_batch(self, packed, chunk: list, bucket: int, out,
-                      stat_kind: str = INSERT, overlap=None) -> None:
+                      stat_kind: str = INSERT, overlap=None) -> int | None:
         be = self.backend
         ids_d, vecs_d, mask = packed
         carry = be.insert_begin(bucket)
@@ -1341,10 +1335,12 @@ class StreamEngine:
         flags = be.ensure_flags()
         for r in range(self.MAX_ROUNDS):
             self._maintain(flags)
-            t0 = time.perf_counter()
-            with self.obs.span("dispatch", kind=stat_kind, bucket=bucket):
+            with self.obs.span("dispatch", kind=stat_kind, bucket=bucket,
+                               rows=len(chunk)) as disp:
                 carry, main_active, lsh_active, fw = be.insert_round(
                     ids_d, vecs_d, carry, main_active, lsh_active, bucket)
+            if r == 0:
+                first = disp.id
             self.n_rounds += 1
             self.n_rounds_by_kind[stat_kind] += 1
             if r == 0 and overlap is not None:
@@ -1352,30 +1348,30 @@ class StreamEngine:
             with self.obs.span("flag_readback", kind=stat_kind):
                 flags = be.read_flags(fw)
             be.after_flags(flags)
-            if self._obs_on:
-                self._h_round[stat_kind].observe(
-                    (time.perf_counter() - t0) * 1e3)
-                if flags:
-                    for bit, c in self._c_flags:
-                        if flags & bit:
-                            c.inc()
+            if self._obs_on and flags:
+                for bit, c in self._c_flags:
+                    if flags & bit:
+                        c.inc()
             if not flags & FLAG_ANY_PENDING:
                 break
         be.count_insert(len(chunk))
         if out is not None:
             for ticket, _, _, _ in chunk:
                 out[ticket] = "ok"
+        return first
 
     def _delete_batch(self, packed, chunk: list, bucket: int, out,
-                      stat_kind: str = DELETE, overlap=None) -> None:
+                      stat_kind: str = DELETE, overlap=None) -> int | None:
         be = self.backend
         ids_d, active = packed
         flags = be.ensure_flags()
         for r in range(self.MAX_ROUNDS):
             self._maintain(flags)
-            t0 = time.perf_counter()
-            with self.obs.span("dispatch", kind=stat_kind, bucket=bucket):
+            with self.obs.span("dispatch", kind=stat_kind, bucket=bucket,
+                               rows=len(chunk)) as disp:
                 pending, fw = be.delete_round(ids_d, active, bucket)
+            if r == 0:
+                first = disp.id
             self.n_rounds += 1
             self.n_rounds_by_kind[stat_kind] += 1
             if r == 0 and overlap is not None:
@@ -1383,19 +1379,17 @@ class StreamEngine:
             with self.obs.span("flag_readback", kind=stat_kind):
                 flags = be.read_flags(fw)
             be.after_flags(flags)
-            if self._obs_on:
-                self._h_round[stat_kind].observe(
-                    (time.perf_counter() - t0) * 1e3)
-                if flags:
-                    for bit, c in self._c_flags:
-                        if flags & bit:
-                            c.inc()
+            if self._obs_on and flags:
+                for bit, c in self._c_flags:
+                    if flags & bit:
+                        c.inc()
             if not flags & FLAG_ANY_PENDING:
                 break
             active = pending
         if out is not None:
             for ticket, _, _, _ in chunk:
                 out[ticket] = "ok"
+        return first
 
     # ------------------------------------------------------------------
     # explicit epochs + stats

@@ -4,8 +4,9 @@ The TPU compiler is installed with JAX and compiles for a topology that
 is described, not attached.  These tests compile the Pallas kernels of
 the served path at ``chip_smoke.py``'s widths with ``interpret=False``
 and assert the kernels survive into the compiled program
-(``tpu_custom_call``): interpret mode on the CPU cannot show a block
-shape or an in-kernel op the chip's compiler refuses.
+(``tpu_custom_call``) under their stable names (``pallas_call(name=)``,
+the op name a profiler capture shows): interpret mode on the CPU cannot
+show a block shape or an in-kernel op the chip's compiler refuses.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and under several test workers
@@ -13,6 +14,7 @@ every worker imports this file.
 """
 import importlib.util
 import os
+import re
 import types
 
 import jax
@@ -73,14 +75,15 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
+def _assert_kernel(kernel, fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call", text), kernel
 
 
 def test_lsh_hash_compiles_for_v5e(one_chip, compiled_kernels, smoke):
     cfg = smoke.smoke_config()
-    _assert_kernel(lambda x, a: ops.lsh_hash(x, a),
+    _assert_kernel("lsh_hash", lambda x, a: ops.lsh_hash(x, a),
                    _spec(one_chip, (smoke.BUCKET, cfg.dim), jnp.float32),
                    _spec(one_chip, (cfg.dim, cfg.L * cfg.M), jnp.float32))
 
@@ -103,7 +106,8 @@ def test_gather_rank_compiles_for_v5e(one_chip, compiled_kernels, smoke,
     else:
         def fn(qv, store, slots, valid):
             return ops.gather_rank(qv, store, slots, valid, cfg.metric)
-    _assert_kernel(fn, *args)
+    _assert_kernel("gather_rank_staged" if staged else "gather_rank", fn,
+                   *args)
 
 
 def test_insert_step_compiles_for_v5e(one_chip, compiled_kernels, smoke):

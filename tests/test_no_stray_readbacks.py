@@ -58,8 +58,6 @@ ALLOWED = {
     ("core/coldtier.py", "ColdManager._merge_cold_impl"),
     ("core/coldtier.py", "ColdManager.spill"),
     ("core/coldtier.py", "_fold_entries"),
-    # snapshot-time shard occupancy summary (host aggregation)
-    ("core/distributed.py", "shard_occupancy"),
     # index: the sanctioned flag readback + epoch/stat paths
     ("core/index.py", "PFOIndex._merge_with_cold"),
     ("core/index.py", "PFOIndex._query_cold"),
@@ -83,7 +81,6 @@ ALLOWED = {
     ("serving/stream.py", "DistBackend._spill"),
     ("serving/stream.py", "DistBackend.after_flags"),
     ("serving/stream.py", "DistBackend.query_rows"),
-    ("serving/stream.py", "DistBackend._mirror_obs"),
     ("serving/stream.py", "DistBackend.ensure_flags"),
     ("serving/stream.py", "DistBackend.read_flags"),
     ("serving/stream.py", "DistBackend.stats"),

@@ -5,18 +5,23 @@ request-grain accounting (``req.*`` decomposition), deadline/SLO
 classes (``obs/slo.py``), and the hard invariant — tracing AND
 per-request accounting add ZERO device readbacks to a steady-state
 round (checked under the JAX transfer guard)."""
+import glob
 import json
+import os
+import re
 import sys
 import time
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from conftest import small_pfo_config
 from repro.core import PFOIndex
-from repro.obs import (NULL_METRIC, NULL_SPAN, Obs, Tracer, report)
+from repro.core import index as pfo
+from repro.obs import (NULL_METRIC, NULL_SPAN, Obs, Tracer, phases, report)
 from repro.obs.metrics import Histogram, MetricsRegistry, render_name
 from repro.serving import StreamConfig, StreamEngine
 
@@ -207,7 +212,8 @@ def test_traced_steady_state_round_zero_extra_readbacks():
     assert rounds >= 1
     assert eng.index.sync_count - before_sync == rounds
     names = {e[0] for e in obs.tracer.events()[n_ev:]}
-    assert {"flush", "pack", "dispatch", "flag_readback"} <= names
+    assert {"flush", "pack", "dispatch", "flag_readback", "req_queue",
+            "req_batch", "req_service", "req_hold"} <= names
     # the accounting observed every request of the guarded flush
     snap = obs.snapshot()
     h = snap["histograms"]["req.e2e_ms{kind=insert}"]
@@ -409,3 +415,204 @@ def test_emit_bench_writes_schema(tmp_path):
     assert "jax" in doc["env"] and "backend" in doc["env"]
     h = doc["metrics"]["histograms"]["stream.round_ms"]
     assert h["count"] == 1 and "p50" in h and "p99" in h
+
+
+# -- request spans, the profiler clock, compiles ---------------------------
+
+def _traced_engine(**obs_kw):
+    cfg = small_pfo_config()
+    obs = Obs(metrics=True, trace=True, trace_capacity=1 << 14, **obs_kw)
+    eng = StreamEngine(PFOIndex(cfg, seed=0, obs=obs),
+                       StreamConfig(max_batch=16, min_batch=16))
+    v = _vecs(96, cfg.dim, seed=11)
+    client = eng.client()
+    for i in range(48):
+        client.insert(i, v[i])
+    eng.flush()                                  # compiles out of the way
+    return eng, obs, client, v
+
+
+def test_request_spans_tile_each_request():
+    """Four spans per ticket: they share the ticket as id and the round's
+    first dispatch span as parent, and their durations sum to the
+    flush's return minus the ticket's arrival."""
+    eng, obs, client, v = _traced_engine()
+    n0 = len(obs.tracer.events())
+    t_arr = time.perf_counter() - 0.005
+    tickets = ([client.query(v[i], 4, t_arrival=t_arr) for i in range(20)]
+               + [client.delete(i, t_arrival=t_arr) for i in range(3)]
+               + [client.insert(60 + i, v[60 + i], t_arrival=t_arr)
+                  for i in range(2)]
+               + [client.update(7, v[90], t_arrival=t_arr)])
+    eng.flush()
+    t_ret = time.perf_counter()
+    ev = obs.tracer.events()[n0:]
+    dispatch = {e[5]: e for e in ev if e[0] == "dispatch"}
+    assert all(e[4]["rows"] <= e[4]["bucket"] for e in dispatch.values())
+    spans: dict = {}
+    for e in ev:
+        if e[0].startswith("req_"):
+            spans.setdefault(e[5], []).append(e)
+    assert set(spans) == set(tickets)
+    for t, sp in spans.items():
+        assert sorted(e[0] for e in sp) == ["req_batch", "req_hold",
+                                            "req_queue", "req_service"]
+        assert len({e[6] for e in sp}) == 1 and sp[0][6] in dispatch
+        total_ms = sum(e[2] for e in sp) / 1e3
+        assert total_ms == pytest.approx((t_ret - t_arr) * 1e3, abs=1.0)
+        queue = next(e for e in sp if e[0] == "req_queue")
+        # the queue span starts at the arrival, on the profiler clock
+        arr_us = (int(t_arr * 1e9) + obs.tracer._anchor) // 1000
+        assert abs(queue[1] - arr_us) <= 1
+
+
+def test_tracer_spans_lie_on_the_profiler_clock(tmp_path):
+    """Each tracer ``flush`` span starts within 100 us of its
+    ``TraceAnnotation`` twin on the capture's host plane; the bridge
+    also keys the compilation cache on op metadata, so a profile never
+    shows a cached executable's stale phase names."""
+    from jax.profiler import ProfileData
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    try:
+        eng, obs, client, v = _traced_engine(jax_annotations=True)
+        assert getattr(jax.config, key) is True
+    finally:
+        jax.config.update(key, before)
+    n0 = len(obs.tracer.events())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            client.query(v[i], 4)
+            eng.flush()
+    finally:
+        jax.profiler.stop_trace()
+    mine = [e[1] * 1000 for e in obs.tracer.events()[n0:]
+            if e[0] == "flush"]
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    start = dict(next(p for p in pd.planes
+                      if p.name == "Task Environment").stats)
+    twins = [ev.start_ns + start["profile_start_time"]
+             for p in pd.planes if p.name.startswith("/host:")
+             for ln in p.lines for ev in ln.events if ev.name == "flush"]
+    assert len(mine) == len(twins) == 3
+    for t in mine:
+        assert min(abs(t - w) for w in twins) < 100_000
+
+
+def test_compile_counter_counts_each_program_once():
+    obs = Obs(metrics=True, trace=True)
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda a: a * 3.0 + 1.0)
+    c = obs.counter("jit.compiles")
+    n0, e0 = c.value, len(obs.tracer.events())
+    f(x).block_until_ready()
+    assert c.value - n0 == 1
+    f(x).block_until_ready()
+    assert c.value - n0 == 1
+    assert [e[0] for e in obs.tracer.events()[e0:]] == ["compile"]
+    assert "jit.compiles" not in Obs(metrics=False).snapshot()["counters"]
+
+
+# -- named device phases ----------------------------------------------------
+
+def _step_paths(step: str) -> set:
+    cfg = small_pfo_config()
+    st = jax.eval_shape(lambda k: pfo.init_state(cfg, k),
+                        jax.random.PRNGKey(0))
+    s = jax.ShapeDtypeStruct
+    b = 16
+    ids, act = s((b,), jnp.int32), s((b,), jnp.bool_)
+    vecs = s((b, cfg.dim), jnp.float32)
+    lowered = {
+        "query_step": lambda: pfo.query_step.lower(st, vecs, cfg, 4),
+        "insert_step": lambda: pfo.insert_step.lower(
+            st, ids, vecs, ids, act, s((b * cfg.L,), jnp.bool_), cfg,
+            b, b),
+        "delete_step": lambda: pfo.delete_step.lower(st, ids, act, cfg,
+                                                     b, b),
+        "merge_step": lambda: pfo.merge_step.lower(st, cfg),
+        "seal_step": lambda: pfo.seal_step.lower(st, cfg),
+    }[step]()
+    return set(re.findall(r'loc\("([^"]*)"',
+                          lowered.as_text(debug_info=True)))
+
+
+@pytest.mark.parametrize("step", sorted(phases.STEPS))
+def test_step_phases_are_named_in_op_metadata(step):
+    found = {phases.phase_of(p) for p in _step_paths(step)}
+    assert set(phases.STEPS[step]) <= found, found
+
+
+def test_phase_of_reads_through_transforms():
+    assert phases.phase_of(
+        "jit(query_step)/vmap(vmap(main_lookup))/vmap(jit(searchsorted))"
+        "/while/body/gather") == "main_lookup"
+    assert phases.phase_of(
+        "jit(query_step)/rank/jit(gather_rank_pallas)/gather_rank"
+        "/pallas_call") == "gather_rank"
+    assert phases.phase_of("jit(merge_step)/vmap(reseal)/sort") == "reseal"
+    assert phases.phase_of("jit(query_step)/concatenate") == \
+        phases.UNSCOPED
+
+
+def _xspace():
+    """One chip: a query_step run 0-30 us (ops: a while loop 0-10 whose
+    body runs a main_lookup op 2-8, rank's gather_rank kernel 10-25, an
+    unnamed op 25-30) and a merge_step run 40-60 us (merge_filter 40-55,
+    reseal 55-60)."""
+    def ev(meta, t0_us, dur_us):
+        return (f"events {{ metadata_id: {meta} offset_ps: "
+                f"{int(t0_us * 1e6)} duration_ps: {int(dur_us * 1e6)} }}")
+
+    def meta(key, name, path=None):
+        stat = (f' stats {{ metadata_id: 9 str_value: "{path}:" }}'
+                if path else "")
+        return (f'event_metadata {{ key: {key} value {{ id: {key} '
+                f'name: "{name}"{stat} }} }}')
+    text = "\n".join([
+        'planes { id: 1 name: "/device:TPU:0"',
+        'lines { id: 1 name: "XLA Modules" timestamp_ns: 0',
+        ev(1, 0, 30), ev(2, 40, 20), "}",
+        'lines { id: 2 name: "XLA Ops" timestamp_ns: 0',
+        ev(8, 0, 10), ev(3, 2, 6), ev(4, 10, 15), ev(5, 25, 5),
+        ev(6, 40, 15),
+        ev(7, 55, 5), "}",
+        meta(1, "jit_query_step(11)"), meta(2, "jit_merge_step(12)"),
+        meta(3, "%fusion.22 = s32[8]", "jit(query_step)/vmap(vmap("
+             "main_lookup))/gather"),
+        meta(4, "%gather_rank.1 = f32[8]", "jit(query_step)/rank/"
+             "gather_rank/pallas_call"),
+        meta(5, "%copy.3 = f32[8]", "jit(query_step)/copy"),
+        meta(6, "%while.59 = s32[8]", "jit(merge_step)/vmap(merge_filter)"
+             "/while"),
+        meta(7, "%sort.2 = s32[8]", "jit(merge_step)/vmap(reseal)/sort"),
+        meta(8, "%while.85 = s32[8]"),
+        'stat_metadata { key: 9 value { id: 9 name: "tf_op" } }',
+        "}"])
+    from jax.profiler import ProfileData
+    return ProfileData.text_proto_to_serialized_xspace(text)
+
+
+def test_device_time_by_phase_on_a_synthetic_capture():
+    xs = _xspace()
+    assert phases.op_paths(xs)["%while.59 = s32[8]"] == \
+        "jit(merge_step)/vmap(merge_filter)/while"
+    got = phases.device_time(xs)
+    # the loop's own time is what its body leaves: 10 - 6 us
+    want = {("jit_query_step", "main_lookup"): 6e-6,
+            ("jit_query_step", "gather_rank"): 15e-6,
+            ("jit_query_step", phases.UNSCOPED): 4e-6 + 5e-6,
+            ("jit_merge_step", "merge_filter"): 15e-6,
+            ("jit_merge_step", "reseal"): 5e-6}
+    assert set(got) == set(want)
+    for k, secs in want.items():
+        assert got[k] == [pytest.approx(secs), 1]
+    # a window clips op time and drops runs outside it
+    got = phases.device_time(xs, window=(20_000, 50_000))
+    assert got[("jit_query_step", "gather_rank")] == \
+        [pytest.approx(5e-6), 1]
+    assert got[("jit_merge_step", "merge_filter")] == \
+        [pytest.approx(10e-6), 1]
