@@ -71,14 +71,14 @@ RECALL_FLOOR = 0.90
 def smoke_config():
     """PFOConfig holding all 1M vectors on one chip, cold tier off.
 
-    A sealed probe reads at most ``snap_budget_per_probe`` entries of
-    its bucket prefix.  ``snap_prefix_bits=20`` keeps a full sealed
-    MainTable segment (~1M entries) at ~1 entry per prefix, so exact id
-    lookups never miss, and an LSH bucket at its planted neighbours
-    sharing the prefix (~20) plus a stranger or two; a budget of 64
-    reads such a bucket whole, also on a shard of the (1, 4) mesh,
-    whose segments mix the tables it owns — so the sharded and the
-    single-chip engine rank the same candidates."""
+    A sealed LSH probe reads at most ``snap_budget_per_probe`` entries
+    of its bucket prefix (the MainTable ring is searched by full key and
+    does not depend on it).  ``snap_prefix_bits=20`` keeps an LSH
+    bucket at its planted neighbours sharing the prefix (~20) plus a
+    stranger or two; a budget of 64 reads such a bucket whole, also on
+    a shard of the (1, 4) mesh, whose segments mix the tables it owns —
+    so the sharded and the single-chip engine rank the same candidates.
+    """
     from repro.core import PFOConfig
     return PFOConfig(dim=DIM, metric="l2", L=10, C=4, m=4,
                      max_leaves_per_tree=4096, max_nodes_per_tree=512,

@@ -470,9 +470,7 @@ def make_dist_query(dcfg: DistConfig, mesh: Mesh, k: int,
         rlocal = jnp.clip(rtree - me * mtps, 0, mtps - 1)
         slot, found = forest_lookup(state.main_forest, rlocal, rh, rid, mcfg)
         msnaps = jax.tree.map(lambda a: a[0], state.main_snaps)
-        sval, sfound = jax.vmap(
-            lambda hh, ii: snap_mod.lookup_exact(msnaps, hh, ii,
-                                                 msnap_cfg))(rh, rid)
+        sval, sfound = snap_mod.lookup_exact(msnaps, rh, rid)
         slot = jnp.where(found, slot, jnp.where(sfound, sval, -1))
         store_l = jax.tree.map(lambda a: a[0], state.store)
         if cfg.cold_enabled:
@@ -739,9 +737,7 @@ def make_dist_delete_round(dcfg: DistConfig, mesh: Mesh, *,
         ltree = jnp.where(own, mtree % mtps, 0)
         slot, found = forest_lookup(state.main_forest, ltree, mh, ids, mcfg)
         msnaps = jax.tree.map(lambda a: a[0], state.main_snaps)
-        sval, sfound = jax.vmap(
-            lambda hh, ii: snap_mod.lookup_exact(msnaps, hh, ii,
-                                                 snap_cfg))(mh, ids)
+        sval, sfound = snap_mod.lookup_exact(msnaps, mh, ids)
         slot = jnp.where(found, slot, jnp.where(sfound, sval, -1))
         store_l = jax.tree.map(lambda a: a[0], state.store)
         if cfg.cold_enabled:

@@ -92,11 +92,7 @@ def _snap_cfg_lsh(cfg: PFOConfig) -> PFOConfig:
 
 def _snap_cfg_main(cfg: PFOConfig) -> PFOConfig:
     cap = cfg.main_n_trees * cfg.main_max_leaves_per_tree
-    # MainTable probes are exact (key, id) lookups — multi-probing
-    # neighbor prefixes cannot find an id that lives under one murmur
-    # key, so the main tier always runs single-probe.
-    return PFOConfig(**{**cfg.__dict__, "snapshot_capacity": cap,
-                        "snap_probes": 1})
+    return PFOConfig(**{**cfg.__dict__, "snapshot_capacity": cap})
 
 
 def init_state(cfg: PFOConfig, key: jax.Array) -> PFOState:
@@ -324,9 +320,7 @@ def _main_lookup(state: PFOState, ids: jax.Array, cfg: PFOConfig):
     mh, mtree = main_table_keys(ids, cfg)
     val, found = forest_lookup(state.main_forest, mtree, mh, ids,
                                main_tree_config(cfg))
-    sval, sfound = jax.vmap(
-        lambda h, i: snap_mod.lookup_exact(state.main_snaps, h, i,
-                                           _snap_cfg_main(cfg)))(mh, ids)
+    sval, sfound = snap_mod.lookup_exact(state.main_snaps, mh, ids)
     slot = jnp.where(found, val, jnp.where(sfound, sval, -1))
     return slot, found | sfound
 
@@ -398,9 +392,11 @@ def query_step(state: PFOState, qvecs: jax.Array, cfg: PFOConfig, k: int):
     trees + sealed segments, dedupe ids, gather vectors via MainTable,
     exact-rank, top-k.
     """
+    q = qvecs.shape[0]
     _, cand = _hot_sealed_candidates(state, qvecs, cfg)
     cids = _dedupe_candidates(cand, state.tombstones, cfg)
-    slot, found = jax.vmap(lambda r: _main_lookup(state, r, cfg))(cids)
+    slot, found = _main_lookup(state, cids.reshape(-1), cfg)
+    slot, found = slot.reshape(q, -1), found.reshape(q, -1)
     return _rank_candidates(state, qvecs, cids, slot, found, cfg, k)
 
 
@@ -435,9 +431,7 @@ def _main_lookup_cold(state: PFOState, ids: jax.Array, cfg: PFOConfig,
     mh, mtree = main_table_keys(ids, cfg)
     val, found = forest_lookup(state.main_forest, mtree, mh, ids,
                                main_tree_config(cfg))
-    sval, sfound = jax.vmap(
-        lambda h, i: snap_mod.lookup_exact(state.main_snaps, h, i,
-                                           _snap_cfg_main(cfg)))(mh, ids)
+    sval, sfound = snap_mod.lookup_exact(state.main_snaps, mh, ids)
     cold_ids = jnp.where(found | sfound, -1, ids)
     if active is not None:
         cold_ids = jnp.where(active, cold_ids, -1)

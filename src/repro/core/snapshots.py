@@ -217,14 +217,35 @@ def pop_oldest(snaps: SnapshotSet, cfg: PFOConfig):
     return shifted, popped
 
 
-def lookup_exact(snaps: SnapshotSet, h: jax.Array, vid: jax.Array,
-                 cfg: PFOConfig):
-    """Exact (key, id) lookup, newest segment first (MainTable path)."""
-    cids, cvals = probe(snaps, h[None], cfg)
-    match = (cids[0] >= 0) & (cids[0] == vid)
-    idx = jnp.argmax(match)                 # first (newest) hit
-    found = jnp.any(match)
-    return jnp.where(found, cvals[0, idx], -1), found
+@jax.named_scope("ring_lookup")
+def lookup_exact(snaps: SnapshotSet, h: jax.Array, vid: jax.Array):
+    """Exact (key, id) lookup over the live segments, newest first
+    (MainTable path): scalars or (N,) arrays -> (val, found), val -1
+    where not found.
+
+    Precondition: a key is a bijection of its id, as
+    ``lsh.main_table_keys`` gives (``murmur3_fmix32(id)``).  A segment
+    is sorted by key, so an id can only sit at the first entry whose
+    key equals its own: one ``searchsorted`` of the full key per
+    segment finds it, and the id comparison confirms it.  The LSH
+    tier's prefix-bucket :func:`probe` is not needed here, and its
+    fixed window would miss an id past the ``snap_budget_per_probe``-th
+    entry of its bucket.  The loop runs ``n_snaps`` trips on the
+    device; a hit in a newer segment takes precedence, and segments at
+    index ``>= n_snaps`` are never read.
+    """
+    last = snaps.keys.shape[1] - 1
+
+    def segment(j, carry):
+        val, found = carry
+        s = snaps.n_snaps - 1 - j
+        pos = jnp.minimum(jnp.searchsorted(snaps.keys[s], h), last)
+        hit = (~found & (vid >= 0) & (snaps.keys[s, pos] == h)
+               & (snaps.ids[s, pos] == vid))
+        return jnp.where(hit, snaps.vals[s, pos], val), found | hit
+
+    init = (jnp.full(h.shape, -1, jnp.int32), jnp.zeros(h.shape, bool))
+    return jax.lax.fori_loop(0, snaps.n_snaps, segment, init)
 
 
 def merge(snaps: SnapshotSet, cfg: PFOConfig,

@@ -27,10 +27,10 @@ import re
 
 STEPS = {
     "query_step": ("hash", "hot_descent", "sealed_probe", "dedupe",
-                   "main_lookup", "rank"),
+                   "main_lookup", "ring_lookup", "rank"),
     "insert_step": ("store_alloc", "main_insert", "lsh_insert", "flags"),
-    "delete_step": ("main_lookup", "lsh_unlink", "main_unlink",
-                    "store_free", "tombstone", "flags"),
+    "delete_step": ("main_lookup", "ring_lookup", "lsh_unlink",
+                    "main_unlink", "store_free", "tombstone", "flags"),
     "merge_step": ("merge_filter", "merge_sort", "reseal"),
     "seal_step": ("reseal",),
 }

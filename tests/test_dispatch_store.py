@@ -166,6 +166,6 @@ def test_snapshot_seal_probe_merge():
                           jnp.ones(1, bool), jnp.int32(2), cfg)
     merged = snap_mod.merge(snaps, cfg)
     val, found = snap_mod.lookup_exact(merged, jnp.uint32(0x10000000),
-                                       jnp.int32(1), cfg)
+                                       jnp.int32(1))
     assert bool(found) and int(val) == 99
     assert int(merged.n_snaps) == 1

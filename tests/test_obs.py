@@ -551,6 +551,9 @@ def test_phase_of_reads_through_transforms():
         "jit(query_step)/vmap(vmap(main_lookup))/vmap(jit(searchsorted))"
         "/while/body/gather") == "main_lookup"
     assert phases.phase_of(
+        "jit(query_step)/main_lookup/ring_lookup/while/body"
+        "/jit(searchsorted)/while/body/gather") == "ring_lookup"
+    assert phases.phase_of(
         "jit(query_step)/rank/jit(gather_rank_pallas)/gather_rank"
         "/pallas_call") == "gather_rank"
     assert phases.phase_of("jit(merge_step)/vmap(reseal)/sort") == "reseal"
